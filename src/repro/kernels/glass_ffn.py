@@ -23,7 +23,9 @@ list outright, while every row still shares one fixed-width compiled grid.
 recompiling per request.  ``None`` keeps the original unscaled program.)
 
 VMEM budget per step (worst assigned case d = 8192, bs = 128, B <= 128):
-x 2 MiB + 3 weight tiles 6 MiB + acc 4 MiB ~= 12 MiB < 16 MiB.
+x 2 MiB + 3 weight tiles 6 MiB + acc 4 MiB ~= 12 MiB before the pipeline
+double-buffers the tiles.  Whether a shape fits the chip's scoped VMEM is
+the TPU compiler's call: tests/test_tpu_compile.py compiles llama3-8b widths.
 """
 from __future__ import annotations
 
@@ -55,28 +57,64 @@ def _tile_contrib(x, wg_ref, wu_ref, wd_ref, *, act: str, gated: bool):
     )
 
 
-def _kernel(idx_ref, x_ref, wg_ref, wu_ref, wd_ref, o_ref, *, act: str, gated: bool):
-    i = pl.program_id(0)
+def _kernel(*refs, act: str, gated: bool, scaled: bool, rowwise: bool):
+    if scaled:
+        _, sc_ref, x_ref, wg_ref, wu_ref, wd_ref, o_ref = refs
+    else:
+        _, x_ref, wg_ref, wu_ref, wd_ref, o_ref = refs
+    i = pl.program_id(1 if rowwise else 0)  # position in the active list
 
     @pl.when(i == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    o_ref[...] += _tile_contrib(x_ref[...], wg_ref, wu_ref, wd_ref, act=act, gated=gated)
+    x = x_ref[0] if rowwise else x_ref[...]
+    contrib = _tile_contrib(x, wg_ref, wu_ref, wd_ref, act=act, gated=gated)
+    if scaled:
+        # the per-step scale lives in SMEM (scalar prefetch): a dynamic lane
+        # index into a VMEM block is not provably 128-aligned, so Mosaic
+        # refuses it
+        k = pl.program_id(0) * pl.num_programs(1) + i if rowwise else i
+        contrib = sc_ref[k] * contrib
+    o_ref[...] += contrib[None] if rowwise else contrib
 
 
-def _kernel_scaled(
-    idx_ref, x_ref, sc_ref, wg_ref, wu_ref, wd_ref, o_ref, *, act: str, gated: bool
-):
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-
-    o_ref[...] += sc_ref[0, i] * _tile_contrib(
-        x_ref[...], wg_ref, wu_ref, wd_ref, act=act, gated=gated
+def _call(x, w_up, w_down, idx, w_gate, block_scale, *, act, block_size,
+          interpret, grid, x_block, x_map, tile):
+    """Shared pallas_call for both kernels: ``tile(*grid_ids, idx)`` is the
+    active block id a grid step streams, ``x_map`` places the x/out block."""
+    d = x.shape[-1]
+    gated = w_gate is not None
+    if not gated:  # dummy ref so the kernel signature stays uniform
+        w_gate = w_up
+    scaled = block_scale is not None
+    n = len(grid)
+    # index maps receive every scalar-prefetch ref; only the block ids steer
+    col = lambda *a: (0, tile(*a[:n], a[n]))
+    row = lambda *a: (tile(*a[:n], a[n]), 0)
+    xm = lambda *a: x_map(*a[:n])
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2 if scaled else 1, grid=grid,
+        in_specs=[
+            pl.BlockSpec(x_block, xm),  # x: resident
+            pl.BlockSpec((d, block_size), col),  # w_gate tile
+            pl.BlockSpec((d, block_size), col),  # w_up tile
+            pl.BlockSpec((block_size, d), row),  # w_down tile
+        ],
+        out_specs=pl.BlockSpec(x_block, xm),
     )
+    fn = pl.pallas_call(
+        functools.partial(
+            _kernel, act=act, gated=gated, scaled=scaled, rowwise=n == 2
+        ),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(x.shape, jnp.float32),
+        interpret=interpret,
+    )
+    scalars = (idx,)
+    if scaled:
+        scalars += (jnp.asarray(block_scale, jnp.float32).reshape(idx.shape),)
+    return fn(*scalars, x, w_gate, w_up, w_down)
 
 
 def glass_ffn_block_sparse(
@@ -93,68 +131,11 @@ def glass_ffn_block_sparse(
 ) -> jax.Array:
     """Returns (B, d) f32. Only active weight blocks are read from HBM."""
     B, d = x.shape
-    m = w_up.shape[1]
-    assert m % block_size == 0, (m, block_size)
-    nb = block_idx.shape[0]
-    gated = w_gate is not None
-    if not gated:  # dummy ref so the kernel signature stays uniform
-        w_gate = w_up
-
-    weight_specs = [
-        pl.BlockSpec((d, block_size), lambda i, idx: (0, idx[i])),  # w_gate tile
-        pl.BlockSpec((d, block_size), lambda i, idx: (0, idx[i])),  # w_up tile
-        pl.BlockSpec((block_size, d), lambda i, idx: (idx[i], 0)),  # w_down tile
-    ]
-    x_spec = pl.BlockSpec((B, d), lambda i, idx: (0, 0))  # x: resident
-    out_spec = pl.BlockSpec((B, d), lambda i, idx: (0, 0))
-    if block_scale is None:
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=(nb,),
-            in_specs=[x_spec] + weight_specs, out_specs=out_spec,
-        )
-        fn = pl.pallas_call(
-            functools.partial(_kernel, act=act, gated=gated),
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((B, d), jnp.float32),
-            interpret=interpret,
-        )
-        return fn(block_idx, x, w_gate, w_up, w_down)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1, grid=(nb,),
-        in_specs=[x_spec, pl.BlockSpec((1, nb), lambda i, idx: (0, 0))] + weight_specs,
-        out_specs=out_spec,
-    )
-    fn = pl.pallas_call(
-        functools.partial(_kernel_scaled, act=act, gated=gated),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, d), jnp.float32),
-        interpret=interpret,
-    )
-    sc = jnp.asarray(block_scale, jnp.float32).reshape(1, nb)
-    return fn(block_idx, x, sc, w_gate, w_up, w_down)
-
-
-def _kernel_rowwise(idx_ref, x_ref, wg_ref, wu_ref, wd_ref, o_ref, *, act: str, gated: bool):
-    i = pl.program_id(1)
-
-    @pl.when(i == 0)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-
-    o_ref[...] += _tile_contrib(x_ref[...], wg_ref, wu_ref, wd_ref, act=act, gated=gated)
-
-
-def _kernel_rowwise_scaled(
-    idx_ref, x_ref, sc_ref, wg_ref, wu_ref, wd_ref, o_ref, *, act: str, gated: bool
-):
-    i = pl.program_id(1)
-
-    @pl.when(i == 0)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-
-    o_ref[...] += sc_ref[0, i] * _tile_contrib(
-        x_ref[...], wg_ref, wu_ref, wd_ref, act=act, gated=gated
+    assert w_up.shape[1] % block_size == 0, (w_up.shape, block_size)
+    return _call(
+        x, w_up, w_down, block_idx, w_gate, block_scale, act=act,
+        block_size=block_size, interpret=interpret, grid=(block_idx.shape[0],),
+        x_block=(B, d), x_map=lambda i: (0, 0), tile=lambda i, idx: idx[i],
     )
 
 
@@ -174,53 +155,27 @@ def glass_ffn_block_sparse_rowwise(
 
     Each serving slot carries its own prompt-adaptive mask, so the active
     block list differs per row.  Grid (B, nb): step (b, i) streams row b's
-    i-th active weight tiles; the row's f32 accumulator lives in its (1, d)
-    output block (consecutive grid steps revisit it, which is safe on TPU's
-    sequential grid).  Rows are processed independently — batching rows that
-    share a block list into the shared-list kernel is a further optimization
-    the engine can apply when masks collide.  ``block_scale`` multiplies row
-    b's i-th tile contribution (per-request GLASS density nested inside the
-    capacity-tier list; 0.0 exactly drops a tile).  Returns (B, d) f32.
+    i-th active weight tiles; the row's f32 accumulator lives in its output
+    block (consecutive grid steps revisit it, which is safe on TPU's
+    sequential grid).  Rows travel as ``(B, 1, d)`` so that a row's block
+    spans the array's last two dims (Mosaic's (8, 128) rule), and the block
+    lists and scales as flat ``B * nb`` SMEM vectors.  Rows are processed
+    independently — batching rows that share a block list into the
+    shared-list kernel is a further optimization the engine can apply when
+    masks collide.  ``block_scale`` multiplies row b's i-th tile
+    contribution (per-request GLASS density nested inside the capacity-tier
+    list; 0.0 exactly drops a tile).  Returns (B, d) f32.
     """
     B, d = x.shape
-    m = w_up.shape[1]
-    assert m % block_size == 0, (m, block_size)
+    assert w_up.shape[1] % block_size == 0, (w_up.shape, block_size)
     assert block_idx.shape[0] == B, (block_idx.shape, B)
     nb = block_idx.shape[1]
-    gated = w_gate is not None
-    if not gated:  # dummy ref so the kernel signature stays uniform
-        w_gate = w_up
-
-    weight_specs = [
-        pl.BlockSpec((d, block_size), lambda b, i, idx: (0, idx[b, i])),  # w_gate tile
-        pl.BlockSpec((d, block_size), lambda b, i, idx: (0, idx[b, i])),  # w_up tile
-        pl.BlockSpec((block_size, d), lambda b, i, idx: (idx[b, i], 0)),  # w_down tile
-    ]
-    x_spec = pl.BlockSpec((1, d), lambda b, i, idx: (b, 0))  # x: row b resident
-    out_spec = pl.BlockSpec((1, d), lambda b, i, idx: (b, 0))
-    if block_scale is None:
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=(B, nb),
-            in_specs=[x_spec] + weight_specs, out_specs=out_spec,
-        )
-        fn = pl.pallas_call(
-            functools.partial(_kernel_rowwise, act=act, gated=gated),
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((B, d), jnp.float32),
-            interpret=interpret,
-        )
-        return fn(block_idx, x, w_gate, w_up, w_down)
-    assert block_scale.shape == block_idx.shape, (block_scale.shape, block_idx.shape)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1, grid=(B, nb),
-        in_specs=[x_spec, pl.BlockSpec((1, nb), lambda b, i, idx: (b, 0))] + weight_specs,
-        out_specs=out_spec,
+    if block_scale is not None:
+        assert block_scale.shape == block_idx.shape, (block_scale.shape, block_idx.shape)
+    out = _call(
+        x.reshape(B, 1, d), w_up, w_down, block_idx.astype(jnp.int32).reshape(B * nb),
+        w_gate, block_scale, act=act, block_size=block_size, interpret=interpret,
+        grid=(B, nb), x_block=(1, 1, d), x_map=lambda b, i: (b, 0, 0),
+        tile=lambda b, i, idx: idx[b * nb + i],
     )
-    fn = pl.pallas_call(
-        functools.partial(_kernel_rowwise_scaled, act=act, gated=gated),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, d), jnp.float32),
-        interpret=interpret,
-    )
-    sc = jnp.asarray(block_scale, jnp.float32)
-    return fn(block_idx, x, sc, w_gate, w_up, w_down)
+    return out.reshape(B, d)

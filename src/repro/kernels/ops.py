@@ -1,16 +1,16 @@
 """Jit'd public entry points for the Pallas kernels.
 
-``interpret`` defaults to True on CPU hosts (kernels execute via the Pallas
-interpreter for correctness work) and should be False on real TPU backends —
-callers flip it via the module-level ``INTERPRET`` or per-call.
+``interpret=None`` (the default) is decided when the kernel is lowered, for
+the platform it is lowered for: the Pallas interpreter for a CPU (where the
+tests run), the compiled Mosaic kernel for a TPU, also when a CPU host
+compiles for a described TPU.  Importing this module touches no backend;
+callers may still force either mode per call.
 """
 from __future__ import annotations
 
 from functools import partial
-from typing import Optional
 
 import jax
-import jax.numpy as jnp
 
 from .flash_attention import flash_attention as _flash
 from .glass_ffn import glass_ffn_block_sparse as _glass_ffn
@@ -18,7 +18,15 @@ from .glass_ffn import glass_ffn_block_sparse_rowwise as _glass_ffn_rowwise
 from .local_stats import local_stats as _local_stats
 from .paged_attention import paged_attention as _paged_attention
 
-INTERPRET = jax.default_backend() == "cpu"
+
+def _by_platform(kernel, args, interpret):
+    """``kernel(*args, interpret=...)``: interpreted when lowered for a CPU,
+    compiled otherwise, unless ``interpret`` forces one."""
+    if interpret is not None:
+        return kernel(*args, interpret=interpret)
+    return jax.lax.platform_dependent(
+        *args, cpu=partial(kernel, interpret=True), default=partial(kernel, interpret=False)
+    )
 
 
 @partial(jax.jit, static_argnames=("act", "block_size", "interpret"))
@@ -27,11 +35,13 @@ def glass_ffn(
     block_size=128, interpret=None,
 ):
     """Block-sparse GLASS FFN decode step: only active weight blocks are read."""
-    it = INTERPRET if interpret is None else interpret
-    return _glass_ffn(
-        x, w_up, w_down, block_idx, w_gate, block_scale=block_scale,
-        act=act, block_size=block_size, interpret=it,
-    )
+    def kernel(x, w_up, w_down, block_idx, w_gate, block_scale, interpret):
+        return _glass_ffn(
+            x, w_up, w_down, block_idx, w_gate, block_scale=block_scale,
+            act=act, block_size=block_size, interpret=interpret,
+        )
+
+    return _by_platform(kernel, (x, w_up, w_down, block_idx, w_gate, block_scale), interpret)
 
 
 @partial(jax.jit, static_argnames=("act", "block_size", "interpret"))
@@ -43,11 +53,13 @@ def glass_ffn_rowwise(
     block list per serving slot (the continuous-batching decode path).
     ``block_scale`` (B, nb) multiplies each row's tile contributions (the
     per-request density hook)."""
-    it = INTERPRET if interpret is None else interpret
-    return _glass_ffn_rowwise(
-        x, w_up, w_down, block_idx, w_gate, block_scale=block_scale,
-        act=act, block_size=block_size, interpret=it,
-    )
+    def kernel(x, w_up, w_down, block_idx, w_gate, block_scale, interpret):
+        return _glass_ffn_rowwise(
+            x, w_up, w_down, block_idx, w_gate, block_scale=block_scale,
+            act=act, block_size=block_size, interpret=interpret,
+        )
+
+    return _by_platform(kernel, (x, w_up, w_down, block_idx, w_gate, block_scale), interpret)
 
 
 @partial(
@@ -58,11 +70,11 @@ def flash_attention(
     q, k, v, *, causal=True, window=None, softcap=None, scale=None,
     block_q=512, block_k=512, interpret=None,
 ):
-    it = INTERPRET if interpret is None else interpret
-    return _flash(
-        q, k, v, causal=causal, window=window, softcap=softcap, scale=scale,
-        block_q=block_q, block_k=block_k, interpret=it,
+    kernel = partial(
+        _flash, causal=causal, window=window, softcap=softcap, scale=scale,
+        block_q=block_q, block_k=block_k,
     )
+    return _by_platform(kernel, (q, k, v), interpret)
 
 
 @partial(jax.jit, static_argnames=("softcap", "scale", "interpret"))
@@ -73,14 +85,13 @@ def paged_attention(
     """Fused paged-attention decode: block-table gather + online-softmax
     attention in one pass — the caller scatters the new k/v rows first.
     ``window`` is a traced int32 scalar (pass 2**30 for global layers)."""
-    it = INTERPRET if interpret is None else interpret
-    return _paged_attention(
-        q, cache_k, cache_v, block_table, cache_len, window,
-        softcap=softcap, scale=scale, interpret=it,
+    kernel = partial(_paged_attention, softcap=softcap, scale=scale)
+    return _by_platform(
+        kernel, (q, cache_k, cache_v, block_table, cache_len, window), interpret
     )
 
 
 @partial(jax.jit, static_argnames=("block_t", "block_m", "interpret"))
 def local_stats(h, *, block_t=256, block_m=512, interpret=None):
-    it = INTERPRET if interpret is None else interpret
-    return _local_stats(h, block_t=block_t, block_m=block_m, interpret=it)
+    kernel = partial(_local_stats, block_t=block_t, block_m=block_m)
+    return _by_platform(kernel, (h,), interpret)

@@ -67,8 +67,6 @@ def model_flops_global(cfg, shape, kind: str, density: float | None) -> float:
 def analyze(compiled, meta: dict, n_devices: int) -> dict:
     ma = compiled.memory_analysis()
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):  # older jax returns one dict per program
-        ca = ca[0] if ca else {}
     txt = compiled.as_text()
     # trip-count-aware HLO walk (raw cost_analysis counts scan bodies once —
     # see hlo_cost.py; raw numbers kept for reference under "xla_raw")

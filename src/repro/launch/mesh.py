@@ -11,14 +11,7 @@ import numpy as np
 
 
 def _make_auto_mesh(shape, axes):
-    """Version-compat mesh constructor.
-
-    ``jax.sharding.AxisType`` only exists on newer jax; older releases build
-    Auto-typed meshes by default, so simply omit the kwarg there."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
+    return jax.make_mesh(shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -336,31 +336,3 @@ def make_resumed_prefill(model: Model, chunk_tokens: int,
         return logits, cache, merge_stat_sums(carry_stats, stats)
 
     return prefill_resumed
-
-
-# ---------------------------------------------------------------------------
-# Replica placement (cluster serving)
-# ---------------------------------------------------------------------------
-
-
-def place_replica(tree, devices):
-    """Commit a pytree (params / KV arenas / the GLASS prior) to one
-    replica's device slice, so every program an engine jit-builds over it
-    runs — and caches — on that slice.
-
-    Committed inputs are what make N replicas' decode ticks
-    dispatch-concurrent: jit follows the argument placement, so replica
-    ``r``'s programs execute on its own devices while the host thread moves
-    on to replica ``r+1``.  Placement is what isolates the compiled-program
-    caches too — each engine already owns its own ``ProgramCache``
-    registry, and distinct input devices give the underlying executables
-    distinct homes.
-
-    ``devices`` is a device list from :func:`~repro.launch.mesh
-    .replica_slices` (the first device carries single-device replicas) or
-    ``None`` for the default-device fallback (single-device test runs: all
-    replicas share one device, correct but serialized)."""
-    if devices is None:
-        return tree
-    dev = devices[0] if isinstance(devices, (list, tuple)) else devices
-    return jax.device_put(tree, dev)

@@ -3,10 +3,9 @@
 The :class:`ClusterEngine` scales the single-engine serving path across
 the ``data`` mesh axis: each replica owns a disjoint ``BlockPool`` shard
 (its own block table, allocator, prefix cache, and GLASS arenas) committed
-to its own device slice (``launch.mesh.replica_slices`` +
-``launch.steps.place_replica``), so the replicas' jitted decode programs
-dispatch concurrently while one host-side dispatcher drains a single
-global queue.
+to the first device of its own slice (``launch.mesh.replica_slices``), so
+the replicas' jitted decode programs dispatch concurrently while one
+host-side dispatcher drains a single global queue.
 
 **Admission** pops the global queue in policy order (the same FIFO /
 PRIORITY / DEADLINE ranks as the per-engine schedulers — a request's rank
@@ -60,12 +59,12 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Dict, List, Optional
 
+import jax
 import numpy as np
 
 from ..core.fusion import GlassConfig
 from ..core.glass import GlassParams
 from ..launch.mesh import replica_slices
-from ..launch.steps import place_replica
 from .engine import MigrationTicket, PagedEngine
 from .lifecycle import ReqState
 from .sampling import SamplingParams
@@ -132,18 +131,27 @@ class ClusterEngine:
         )
         self.replicas: List[PagedEngine] = []
         for r, devs in enumerate(slices):
-            eng = PagedEngine(
-                model,
-                place_replica(params, devs),
-                glass=glass,
-                global_prior=(
-                    place_replica(global_prior, devs)
-                    if global_prior is not None else None
-                ),
-                policy=policy,
-                **engine_kw,
-            )
-            eng.pool.cache = place_replica(eng.pool.cache, devs)
+            # a replica lives on the first device of its slice: params and
+            # prior are committed there, and everything the engine allocates
+            # (KV pool, state rows) is created there, never staged on the
+            # default device first
+            dev = devs[0] if devs is not None else None
+            put = (lambda t: t) if dev is None else (lambda t: jax.device_put(t, dev))
+            with jax.default_device(dev):
+                eng = PagedEngine(
+                    model,
+                    put(params),
+                    glass=glass,
+                    global_prior=(
+                        put(global_prior) if global_prior is not None else None
+                    ),
+                    policy=policy,
+                    **engine_kw,
+                )
+            # commit the pool where it was allocated, like the params: jit
+            # keys its cache on commitment, so an uncommitted first call
+            # would compile every pool-reading program twice
+            eng.pool.cache = put(eng.pool.cache)
             eng.programs.namespace = f"replica{r}"
             self.replicas.append(eng)
         self.devices = slices

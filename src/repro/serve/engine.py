@@ -411,9 +411,19 @@ class GlassSlotState:
         return fn
 
     def _init_arena(self, rows):
+        # the arena lives with the engine's params, committed where they are
+        # (a replica's arenas never stage on the default device; migrated-in
+        # rows arrive as host numpy and carry no placement of their own).
+        # Uncommitted params keep it uncommitted: jit keys its cache on
+        # commitment, so a mixed arena would compile every program twice
         ax = self.slot_axis
+        leaf = jax.tree.leaves(self.params)[0]
+        dev = leaf.sharding if leaf.committed else None
         return jax.tree.map(
-            lambda r: jnp.zeros(r.shape[:ax] + (self.max_slots,) + r.shape[ax + 1 :], r.dtype),
+            lambda r: jnp.zeros(
+                r.shape[:ax] + (self.max_slots,) + r.shape[ax + 1 :], r.dtype,
+                device=dev,
+            ),
             rows,
         )
 

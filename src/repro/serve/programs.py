@@ -71,16 +71,10 @@ class ProgramCache:
         self._fns[name] = jitted
         return jitted
 
-    def _count(self, fn) -> int:
-        sz = getattr(fn, "_cache_size", None)
-        if sz is None:  # older jax: no observability, report 0 not a crash
-            return 0
-        return int(sz())
-
     def sizes(self) -> Dict[str, int]:
         """Compiled-variant count per registered program (namespace-qualified
         names when a namespace is set)."""
-        return {self._qual(name): self._count(fn) for name, fn in self._fns.items()}
+        return {self._qual(name): int(fn._cache_size()) for name, fn in self._fns.items()}
 
     def total(self) -> int:
         return sum(self.sizes().values())

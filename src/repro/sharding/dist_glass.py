@@ -13,10 +13,10 @@ from typing import Dict
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-from ..compat import shard_map
 from ..models.common import ModelConfig
 
 

@@ -17,10 +17,10 @@ from typing import Any, Callable, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-from ..compat import shard_map
 from .optim import OptConfig, adamw_update
 
 

@@ -35,7 +35,7 @@ import numpy as np
 import pytest
 
 from tests.helpers import run_with_devices
-from tests.hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import GlassConfig
 from repro.models import ModelConfig, build_model

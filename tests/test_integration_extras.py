@@ -4,7 +4,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from tests.hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.configs import ASSIGNED, get_config
 from repro.core.fusion import GlassConfig, glass_scores, select_blocks

@@ -5,7 +5,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from tests.hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.glass_ffn import glass_ffn_block_sparse
@@ -105,3 +105,27 @@ def test_ops_jit_wrappers():
         np.asarray(ls_op(h, block_t=64, block_m=128)),
         np.asarray(local_stats_ref(h)), atol=1e-4, rtol=1e-4,
     )
+
+
+@pytest.mark.parametrize("module", ["repro.kernels.ops", "repro.serve.cluster", "benchmarks.tables"])
+def test_import_initialises_no_backend(module):
+    """Importing the kernels, the serving stack or a benchmark that spawns
+    workers must not claim a device: on a TPU host the process that first
+    initialises a backend holds the chip, and the interpret-or-compile
+    choice is made when a kernel is traced, not when its module loads."""
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    root = Path(__file__).resolve().parent.parent
+    code = (
+        f"import {module}\n"
+        "from jax._src import xla_bridge\n"
+        "print(sorted(xla_bridge._backends))\n"
+    )
+    env = dict(os.environ, PYTHONPATH=os.pathsep.join([str(root / "src"), str(root)]))
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True, text=True,
+                         env=env, timeout=300, cwd=root)
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.strip() == "[]", out.stdout

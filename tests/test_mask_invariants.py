@@ -5,15 +5,12 @@ units per layer at any density; lam=0 reduces to GRIFFIN (local-only
 ranking, prior-independent); lam=1 reduces to the static global mask
 (local-independent); and the slot-stacked batched path is exactly the
 per-request path.
-
-Runs under real ``hypothesis`` when installed, else the deterministic
-fallback in tests/hypothesis_compat.py.
 """
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from tests.hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import GlassConfig, build_masks
 from repro.core.fusion import select_topk

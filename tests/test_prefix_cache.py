@@ -33,7 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from tests.hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import GlassConfig
 from repro.models import ModelConfig, build_model
