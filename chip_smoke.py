@@ -58,33 +58,6 @@ def check(ok: bool, what: str) -> None:
         raise RuntimeError(f"check failed: {what}")
 
 
-class CompileMeter:
-    """Seconds of XLA backend compilation (persistent-cache reads included:
-    a hit replaces a compile) and the persistent cache's hits and misses.
-    Tracing and lowering are left out; their events nest."""
-
-    def __init__(self, jax):
-        self.secs = 0.0
-        self.hits = 0
-        self.misses = 0
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _duration(self, event, duration_secs, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.secs += duration_secs
-
-    def _event(self, event, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.hits += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            self.misses += 1
-
-    def line(self) -> str:
-        return (f"compile_s={self.secs:.1f} persistent_cache_hits={self.hits} "
-                f"persistent_cache_misses={self.misses}")
-
-
 def memory(dev) -> str:
     st = dev.memory_stats() or {}
     return (f"bytes_in_use={st.get('bytes_in_use')} "
@@ -346,11 +319,13 @@ def main() -> None:
     if dev.platform != "tpu":
         sys.exit(f"chip_smoke: needs a TPU; JAX found platform {dev.platform!r}")
 
-    sys.path.insert(0, str(ROOT / "src"))
+    sys.path[:0] = [str(ROOT), str(ROOT / "src")]
     import jax.numpy as jnp
     import numpy as np
 
-    meter = CompileMeter(jax)
+    from bench.build import compile_meter
+
+    meter = compile_meter(jax)
     device = {"platform": dev.platform, "kind": dev.device_kind,
               "count": len(jax.devices())}
     log(f"device: {json.dumps(device)} jax={jax.__version__} "

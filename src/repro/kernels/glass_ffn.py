@@ -82,7 +82,10 @@ def _kernel(*refs, act: str, gated: bool, scaled: bool, rowwise: bool):
 def _call(x, w_up, w_down, idx, w_gate, block_scale, *, act, block_size,
           interpret, grid, x_block, x_map, tile):
     """Shared pallas_call for both kernels: ``tile(*grid_ids, idx)`` is the
-    active block id a grid step streams, ``x_map`` places the x/out block."""
+    active block id a grid step streams, ``x_map`` places the x/out block.
+    The call is named ``glass_ffn_rowwise`` (a 2-D grid) or
+    ``glass_ffn_shared``: on a TPU its custom call's HLO instruction takes
+    the name, and so does the operation in a profiler trace."""
     d = x.shape[-1]
     gated = w_gate is not None
     if not gated:  # dummy ref so the kernel signature stays uniform
@@ -110,6 +113,7 @@ def _call(x, w_up, w_down, idx, w_gate, block_scale, *, act, block_size,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(x.shape, jnp.float32),
         interpret=interpret,
+        name="glass_ffn_rowwise" if n == 2 else "glass_ffn_shared",
     )
     scalars = (idx,)
     if scaled:

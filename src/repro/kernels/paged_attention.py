@@ -195,5 +195,6 @@ def paged_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, T, K * G, hd), q.dtype),
         interpret=interpret,
+        name="paged_attention",
     )(tab, clen, wnd, q.reshape(B, T, K * G, hd), cache_k, cache_v, same, row)
     return out.reshape(B, T, K, G, hd)

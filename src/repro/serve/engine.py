@@ -46,6 +46,7 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..core.fusion import GlassConfig, merge_stat_sums
 from ..core.glass import (
@@ -58,6 +59,7 @@ from ..core.glass import (
     snapshot_stat_sums,
 )
 from ..models.api import Model
+from ..models.transformer import layer_windows
 from .kv_pool import (
     BlockPool,
     KVPool,
@@ -798,6 +800,47 @@ class ContinuousEngine(_QueueEngineBase):
 # ---------------------------------------------------------------------------
 
 
+def ffn_tile_fetches(ids: np.ndarray, groups: Tuple[int, ...] = (),
+                     perm: Optional[np.ndarray] = None, T: int = 1) -> int:
+    """Weight-tile fetches of one decode step's ``glass_ffn`` calls, summed
+    over layers.  ``ids`` (B, L, nb) is each slot's block list as the
+    kernels receive it (zeros where the arena row is cleared); ``groups``
+    and ``perm`` are the decode call's shared-list batching.  A shared-list
+    call streams its group's one list; the rowwise call walks its rows'
+    lists in grid order, and a grid step fetches only when its tile id
+    differs from the previous step's (the pipeline skips an unchanged
+    block).  ``T`` queries per slot repeat each slot's rows."""
+    rows = ids if T == 1 else np.repeat(ids, T, axis=0)
+    L = ids.shape[1]
+    fetches = 0
+    if groups:
+        order = (np.asarray(perm)[:, None] * T + np.arange(T)).reshape(-1)
+        off = 0
+        for g in groups:
+            one = rows[order[off]]  # (L, nb): the group's list
+            fetches += L + int(np.count_nonzero(one[:, 1:] != one[:, :-1]))
+            off += g * T
+        rows = rows[order[off:]]
+    if len(rows):  # the rowwise call: within each list, then across rows
+        fetches += L + int(np.count_nonzero(rows[:, :, 1:] != rows[:, :, :-1]))
+        fetches += int(np.count_nonzero(rows[1:, :, 0] != rows[:-1, :, -1]))
+    return fetches
+
+
+def attn_live_blocks(lengths: np.ndarray, steps: int, windows, block_size: int) -> int:
+    """KV blocks holding live, window-capped rows that the queries of one
+    decode call attend, summed over rows, queries and layers: row ``r``'s
+    ``j``-th query sits at position ``lengths[r] + j`` and sees the rows
+    within the layer's window of it, its own included.  ``windows`` is
+    ``(window, layers)`` pairs."""
+    q = np.asarray(lengths, np.int64)[:, None] + np.arange(steps)
+    last = q // block_size
+    total = 0
+    for w, n in windows:
+        total += n * int((last - np.maximum(q - w + 1, 0) // block_size).sum())
+    return total + q.size * sum(n for _, n in windows)
+
+
 class PagedEngine(_QueueEngineBase):
     """Continuous batching over a paged KV block table, driven by an
     explicit per-request lifecycle state machine (``serve.lifecycle``).
@@ -994,6 +1037,17 @@ class PagedEngine(_QueueEngineBase):
         self.migrations_in = 0
         self.migration_bytes = 0  # wire bytes exported by migrate_out
         self.grouped_rows = 0  # decode row-ticks served by the shared-list kernel
+        # decode waste, summed over decode steps and layers (``counters``):
+        # the tile fetches the glass_ffn grids make against the distinct
+        # tiles the decoding rows keep, and the blocks the attention walks
+        # (max_slots x the block table's width) against those holding the
+        # decoding rows' live, window-capped K/V.  Every call over the
+        # target tier counts; a speculative draft scan (whose tier's lists
+        # have no host copy) does not
+        self.ffn_tiles_read = 0
+        self.ffn_tiles_union = 0
+        self.attn_blocks_walked = 0
+        self.attn_blocks_live = 0
         self.admission_waits: List[int] = []  # first-admission latency per request
         self.decode_chunk = max(1, decode_chunk)
         # speculative-decode knob + telemetry
@@ -1018,6 +1072,15 @@ class PagedEngine(_QueueEngineBase):
                 "family has no attention block table to fuse over"
             )
         self.attn_mode = attn_mode
+        # per-layer windows of the attention the transformer decode step
+        # walks (the hybrid's shared block and recurrent families: none)
+        walks = has_paged and model.cfg.family not in ("hybrid", "ssm")
+        self._attn_windows = (
+            [(int(w), int(n)) for w, n in
+             zip(*np.unique(np.asarray(layer_windows(model.cfg)), return_counts=True))]
+            if walks else []
+        )
+        self._attn_layers = sum(n for _, n in self._attn_windows)
         if verify_mode == "parallel" and has_state:
             raise ValueError(
                 "verify_mode='parallel' targets attention-backed families; "
@@ -1466,6 +1529,8 @@ class PagedEngine(_QueueEngineBase):
         e.prefill_pos = ticket.prefill_pos
         e.cached_rows = 0  # no shared blocks survive a cross-pool move
         e.glass_key = ticket.glass_key
+        if e.glass_key is not None:
+            e.ffn_tiles = self._tile_map(e.glass_key)
         e.swap = self.pool.adopt_wire(ticket.wire)
         e.glass_rows = ticket.glass_rows
         e.pstats = restore_stat_sums(ticket.pstats) if ticket.mid_prefill else None
@@ -1743,7 +1808,7 @@ class PagedEngine(_QueueEngineBase):
             self.pool.free(slot)
             e.pstats = None
             e.prefill_pos = 0
-            e.glass_key = None
+            e.glass_key = e.ffn_tiles = None
             e.replay_left = 0
             self.lc.to(e, ReqState.PREEMPTED_RECOMPUTE)
             self.scheduler.requeue(e.req)
@@ -1779,7 +1844,7 @@ class PagedEngine(_QueueEngineBase):
         e.glass_rows = None
         e.pstats = None
         e.prefill_pos = 0
-        e.glass_key = None
+        e.glass_key = e.ffn_tiles = None
         e.replay_left = 0
         self.lc.to(e, ReqState.PREEMPTED_RECOMPUTE)
         self.scheduler.requeue(e.req)
@@ -1919,13 +1984,18 @@ class PagedEngine(_QueueEngineBase):
         if not pre:
             return False
         e = min(pre, key=lambda e: (e.admitted_step, e.uid))
-        r = e.req
-        slot = e.slot
-        pos = e.prefill_pos
         # chunks never cross the prompt boundary: GLASS running-sum stats
         # must cover EXACTLY the prompt tokens so a recompute replay (same
         # boundaries, same tokens) reproduces the identical fused mask
-        T = min(self.chunk_tokens, len(r.prompt) - pos)
+        T = min(self.chunk_tokens, len(e.req.prompt) - e.prefill_pos)
+        with TraceAnnotation("engine.prefill", uid=e.uid, tokens=T):
+            return self._prefill_chunk(e, T, finished)
+
+    def _prefill_chunk(self, e: LiveRequest, T: int,
+                       finished: List[RequestOutput]) -> bool:
+        r = e.req
+        slot = e.slot
+        pos = e.prefill_pos
         while not self.pool.ensure_capacity(slot, pos + T):
             if not self._preempt_for_capacity(protect=e):
                 # sole in-flight request: cannot happen (submit validates the
@@ -1968,39 +2038,47 @@ class PagedEngine(_QueueEngineBase):
             )
         self.max_prefill_tokens_per_tick = max(self.max_prefill_tokens_per_tick, T)
         if pos + T == len(r.prompt):  # final chunk: finalize GLASS + first token
-            if self.glass_slots is not None:
-                rows = self.glass_slots.admit(
-                    [slot], [e.pstats], overrides=[self._glass_override(e)]
-                )
-                if self._mode == "block_sparse":
-                    # host copy of the (L, nb_keep) active-block list AND
-                    # its tile scales: the group-by key for the shared-list
-                    # decode kernel — rows may only batch through one shared
-                    # grid when both their lists and their per-request
-                    # density scales coincide
-                    e.glass_key = (
-                        np.asarray(rows["idx"][:, 0]).tobytes()
-                        + np.asarray(rows["scale"][:, 0]).tobytes()
-                    )
-            e.pstats = None
-            self.lc.to(e, ReqState.RUNNING)
-            if e.outputs:
-                # recompute resume: the generated prefix is replayed through
-                # decode as forced tokens — nothing is re-sampled (and the
-                # counter-based draws would regenerate it bit-identically
-                # anyway)
-                e.pending = e.outputs[0]
-                e.replay_left = len(e.outputs) - 1
-            else:
-                first = self._first_token_for(e, np.asarray(last[0], np.float32))
-                e.outputs = [first]
-                e.pending = first
-                e.rng_pos = 1
-                if first in e.sp.stop_set:
-                    self._finish(slot, finished, self._stop_reason(e, first))
-                elif len(e.outputs) >= r.max_new:
-                    self._finish(slot, finished, "length")
+            with TraceAnnotation("engine.prefill.finalize"):
+                self._finalize_prefill(e, last, finished)
         return True
+
+    def _finalize_prefill(self, e: LiveRequest, last, finished: List[RequestOutput]) -> None:
+        """After the final chunk: the request's GLASS rows, then its first
+        token (or the replay of its generated prefix)."""
+        slot = e.slot
+        if self.glass_slots is not None:
+            rows = self.glass_slots.admit(
+                [slot], [e.pstats], overrides=[self._glass_override(e)]
+            )
+            if self._mode == "block_sparse":
+                # host copy of the (L, nb_keep) active-block list AND its
+                # tile scales: the group-by key for the shared-list decode
+                # kernel — rows may only batch through one shared grid when
+                # both their lists and their per-request density scales
+                # coincide — and the tiles it keeps, for the waste counters
+                e.glass_key = (
+                    np.asarray(rows["idx"][:, 0]).tobytes()
+                    + np.asarray(rows["scale"][:, 0]).tobytes()
+                )
+                e.ffn_tiles = self._tile_map(e.glass_key)
+        e.pstats = None
+        self.lc.to(e, ReqState.RUNNING)
+        if e.outputs:
+            # recompute resume: the generated prefix is replayed through
+            # decode as forced tokens — nothing is re-sampled (and the
+            # counter-based draws would regenerate it bit-identically
+            # anyway)
+            e.pending = e.outputs[0]
+            e.replay_left = len(e.outputs) - 1
+        else:
+            first = self._first_token_for(e, np.asarray(last[0], np.float32))
+            e.outputs = [first]
+            e.pending = first
+            e.rng_pos = 1
+            if first in e.sp.stop_set:
+                self._finish(slot, finished, self._stop_reason(e, first))
+            elif len(e.outputs) >= e.req.max_new:
+                self._finish(slot, finished, "length")
 
     def _horizon(self, prefill_pending: bool) -> int:
         """Largest safe fused-decode length: 1 while any prefill is pending
@@ -2094,6 +2172,57 @@ class PagedEngine(_QueueEngineBase):
             btab = np.zeros((B, 1), np.int32)
         return decoding, lengths, toks, btab
 
+    # -- decode waste counters ----------------------------------------------
+
+    def counters(self) -> Dict[str, int]:
+        """Every cumulative counter a measurement diffs over a window."""
+        return dict(
+            t=self.t, slot_steps=self.slot_steps, kv_row_ticks=self.kv_row_ticks,
+            ffn_tiles_read=self.ffn_tiles_read, ffn_tiles_union=self.ffn_tiles_union,
+            attn_blocks_walked=self.attn_blocks_walked,
+            attn_blocks_live=self.attn_blocks_live,
+        )
+
+    @staticmethod
+    def _key_lists(key: bytes) -> Tuple[np.ndarray, np.ndarray]:
+        """The (L, nb_keep) block ids and tile scales a ``glass_key`` holds."""
+        half = len(key) // 2
+        return np.frombuffer(key[:half], np.int32), np.frombuffer(key[half:], np.float32)
+
+    def _tile_map(self, key: bytes) -> np.ndarray:
+        """(L, n_tiles) bool: the FFN tiles a request keeps (scale > 0)."""
+        ids, scale = self._key_lists(key)
+        L = self.model.cfg.n_layers
+        m = np.zeros((L, self.model.cfg.d_ff // self.glass.block_size), bool)
+        r, c = np.nonzero(scale.reshape(L, -1))
+        m[r, ids.reshape(L, -1)[r, c]] = True
+        return m
+
+    def _count_decode(self, run: List[LiveRequest], lengths: np.ndarray, H: int,
+                      T: int, nb: int, groups: Tuple[int, ...] = (),
+                      perm: Optional[np.ndarray] = None) -> None:
+        """Add one decode call to the waste counters: ``run`` decodes H
+        steps of ``T`` queries from ``lengths`` through a block table
+        ``nb`` wide.  Host copies only; nothing waits on the device."""
+        B = self.pool.max_slots
+        if self._attn_layers:
+            self.attn_blocks_walked += H * T * self._attn_layers * B * nb
+            self.attn_blocks_live += attn_live_blocks(
+                lengths, H * T, self._attn_windows, self.pool.block_size)
+        if self._mode != "block_sparse":
+            return
+        L = self.model.cfg.n_layers
+        ids = None
+        for e in self.lc.in_state(ReqState.RUNNING, ReqState.SPECULATING):
+            if e.glass_key is not None:  # every other arena row is cleared
+                row = self._key_lists(e.glass_key)[0].reshape(L, -1)
+                if ids is None:
+                    ids = np.zeros((B,) + row.shape, np.int32)
+                ids[e.slot] = row
+        self.ffn_tiles_read += H * ffn_tile_fetches(ids, groups, perm, T)
+        union = np.logical_or.reduce([e.ffn_tiles for e in run])
+        self.ffn_tiles_union += H * T * int(np.count_nonzero(union))
+
     # -- speculative decode (draft tier -> multi-token verify -> rollback) ---
 
     def _spec_round(self, run: List[LiveRequest]) -> Tuple[List[LiveRequest], int]:
@@ -2164,39 +2293,44 @@ class PagedEngine(_QueueEngineBase):
         from the checkpoint before verification.  Draft tokens are appended
         to ``outputs`` PROVISIONALLY (``spec_len`` marks them): nothing may
         read them as ground truth until the target tier accepts them."""
-        for e in run:
-            n = int(self.pool.lengths[e.slot])
-            e.spec_ckpt = SpecCheckpoint(
-                rows=n, ensured=n + k + 1, out_len=len(e.outputs),
-                pending=e.pending, state_rows=self.pool.save_state_rows(e.slot),
+        with TraceAnnotation("engine.decode.prepare", H=k, rows=len(run)):
+            for e in run:
+                n = int(self.pool.lengths[e.slot])
+                e.spec_ckpt = SpecCheckpoint(
+                    rows=n, ensured=n + k + 1, out_len=len(e.outputs),
+                    pending=e.pending, state_rows=self.pool.save_state_rows(e.slot),
+                )
+                self.lc.to(e, ReqState.SPECULATING)
+            decoding, lengths, toks, btab = self._scan_inputs(run, k + 1)
+            pos0, seeds, temp, topk, topp, minp, gmask, stop_ids, sampled = (
+                self._policy_inputs(run, with_stops=False)
             )
-            self.lc.to(e, ReqState.SPECULATING)
-        decoding, lengths, toks, btab = self._scan_inputs(run, k + 1)
-        pos0, seeds, temp, topk, topp, minp, gmask, stop_ids, sampled = (
-            self._policy_inputs(run, with_stops=False)
-        )
-        B = self.pool.max_slots
-        # sampled slots draft with the SAME counter-based keys the target
-        # verdict will use — proposal j for position out_len + j draws key
-        # (seed, out_len + j) from the DRAFT logits, so a proposal matches
-        # the verdict exactly when both tiers would emit the same token
-        seq, _, _, arena = self._decode(
-            self.params, self.pool.cache, jnp.asarray(lengths), jnp.asarray(toks),
-            jnp.asarray(btab), jnp.asarray(decoding), self.glass_slots.draft_arena,
-            jnp.zeros((k, B), jnp.int32), jnp.zeros((k, B), bool),
-            jnp.zeros((B,), jnp.int32),
-            jnp.asarray(pos0), jnp.asarray(seeds), jnp.asarray(temp),
-            jnp.asarray(topk), jnp.asarray(topp), jnp.asarray(minp),
-            jnp.asarray(gmask), jnp.asarray(stop_ids),
-            (), sampled,
-        )
+            B = self.pool.max_slots
+            # sampled slots draft with the SAME counter-based keys the target
+            # verdict will use — proposal j for position out_len + j draws key
+            # (seed, out_len + j) from the DRAFT logits, so a proposal matches
+            # the verdict exactly when both tiers would emit the same token
+            args = (
+                self.params, self.pool.cache, jnp.asarray(lengths), jnp.asarray(toks),
+                jnp.asarray(btab), jnp.asarray(decoding), self.glass_slots.draft_arena,
+                jnp.zeros((k, B), jnp.int32), jnp.zeros((k, B), bool),
+                jnp.zeros((B,), jnp.int32),
+                jnp.asarray(pos0), jnp.asarray(seeds), jnp.asarray(temp),
+                jnp.asarray(topk), jnp.asarray(topp), jnp.asarray(minp),
+                jnp.asarray(gmask), jnp.asarray(stop_ids),
+                (), sampled,
+            )
+        with TraceAnnotation("engine.decode.dispatch"):
+            seq, _, _, arena = self._decode(*args)
         self.pool.cache = arena
-        seq = np.asarray(seq)  # (k, B) draft proposals d_1..d_k
-        for e in run:
-            # provisional: rng_pos intentionally does NOT advance until the
-            # target tier accepts
-            e.outputs.extend(int(x) for x in seq[:, e.slot])
-            e.spec_len = k
+        with TraceAnnotation("engine.decode.wait"):
+            seq = np.asarray(seq)  # (k, B) draft proposals d_1..d_k
+        with TraceAnnotation("engine.decode.commit"):
+            for e in run:
+                # provisional: rng_pos intentionally does NOT advance until
+                # the target tier accepts
+                e.outputs.extend(int(x) for x in seq[:, e.slot])
+                e.spec_len = k
 
     def _spec_verify(self, run: List[LiveRequest], k: int,
                      finished: List[RequestOutput]) -> None:
@@ -2214,58 +2348,75 @@ class PagedEngine(_QueueEngineBase):
         KV rows, release speculative blocks.  Accepted tokens that hit the
         request's stop set finish it early (truncated at the stop token,
         blocks freed this tick)."""
-        has_state = self.pool.has_state
-        if has_state:
-            # the draft advanced recurrent state k steps under the draft
-            # tier; verification must start from the pre-draft carry
-            for e in run:
-                self.pool.restore_state_rows(e.slot, e.spec_ckpt.state_rows)
-        decoding, lengths, toks, btab = self._scan_inputs(run, k + 1)
-        pos0, seeds, temp, topk, topp, minp, gmask, stop_ids, sampled = (
-            self._policy_inputs(run, with_stops=False, H_offset_ckpt=True)
-        )
-        B = self.pool.max_slots
-        ftoks = np.zeros((k + 1, B), np.int32)
-        fmask = np.zeros((k + 1, B), bool)
-        for e in run:
-            ck = e.spec_ckpt
-            toks[e.slot] = ck.pending  # unchanged during draft, but explicit
-            for j in range(k):
-                ftoks[j, e.slot] = e.outputs[ck.out_len + j]
-                fmask[j, e.slot] = True
-        groups, perm = self._ffn_grouping(run)
-        if perm is None:
-            perm = np.zeros((B,), np.int32)
-        if self._verify_parallel:
-            # ONE T = k+1 forward instead of the k+1-step scan: the feed is
-            # fully known up front (pending + drafts, all forced), and the
-            # per-query kernel grid keeps logits bitwise equal to the scan
-            feed = np.zeros((B, k + 1), np.int32)
-            feed[:, 0] = toks
+        with TraceAnnotation("engine.decode.prepare", H=k + 1, rows=len(run)):
+            if self.pool.has_state:
+                # the draft advanced recurrent state k steps under the draft
+                # tier; verification must start from the pre-draft carry
+                for e in run:
+                    self.pool.restore_state_rows(e.slot, e.spec_ckpt.state_rows)
+            decoding, lengths, toks, btab = self._scan_inputs(run, k + 1)
+            pos0, seeds, temp, topk, topp, minp, gmask, stop_ids, sampled = (
+                self._policy_inputs(run, with_stops=False, H_offset_ckpt=True)
+            )
+            B = self.pool.max_slots
+            ftoks = np.zeros((k + 1, B), np.int32)
+            fmask = np.zeros((k + 1, B), bool)
             for e in run:
                 ck = e.spec_ckpt
+                toks[e.slot] = ck.pending  # unchanged during draft, but explicit
                 for j in range(k):
-                    feed[e.slot, j + 1] = e.outputs[ck.out_len + j]
-            tgt, arena = self._pverify(
-                self.params, self.pool.cache, jnp.asarray(lengths),
-                jnp.asarray(feed), jnp.asarray(btab), self.glass_slots.arena,
-                jnp.asarray(perm), jnp.asarray(pos0), jnp.asarray(seeds),
-                jnp.asarray(temp), jnp.asarray(topk), jnp.asarray(topp),
-                jnp.asarray(minp), jnp.asarray(gmask),
-                groups, sampled,
-            )
-        else:
-            _, tgt, _, arena = self._decode(
-                self.params, self.pool.cache, jnp.asarray(lengths), jnp.asarray(toks),
-                jnp.asarray(btab), jnp.asarray(decoding), self.glass_slots.arena,
-                jnp.asarray(ftoks), jnp.asarray(fmask), jnp.asarray(perm),
-                jnp.asarray(pos0), jnp.asarray(seeds), jnp.asarray(temp),
-                jnp.asarray(topk), jnp.asarray(topp), jnp.asarray(minp),
-                jnp.asarray(gmask), jnp.asarray(stop_ids),
-                groups, sampled,
-            )
+                    ftoks[j, e.slot] = e.outputs[ck.out_len + j]
+                    fmask[j, e.slot] = True
+            groups, perm = self._ffn_grouping(run)
+            H, T = (1, k + 1) if self._verify_parallel else (k + 1, 1)
+            self._count_decode(run, lengths[decoding], H, T, btab.shape[1], groups, perm)
+            if perm is None:
+                perm = np.zeros((B,), np.int32)
+            if self._verify_parallel:
+                # ONE T = k+1 forward instead of the k+1-step scan: the feed
+                # is fully known up front (pending + drafts, all forced), and
+                # the per-query kernel grid keeps logits bitwise equal to the
+                # scan
+                feed = np.zeros((B, k + 1), np.int32)
+                feed[:, 0] = toks
+                for e in run:
+                    ck = e.spec_ckpt
+                    for j in range(k):
+                        feed[e.slot, j + 1] = e.outputs[ck.out_len + j]
+                args = (
+                    self.params, self.pool.cache, jnp.asarray(lengths),
+                    jnp.asarray(feed), jnp.asarray(btab), self.glass_slots.arena,
+                    jnp.asarray(perm), jnp.asarray(pos0), jnp.asarray(seeds),
+                    jnp.asarray(temp), jnp.asarray(topk), jnp.asarray(topp),
+                    jnp.asarray(minp), jnp.asarray(gmask),
+                    groups, sampled,
+                )
+            else:
+                args = (
+                    self.params, self.pool.cache, jnp.asarray(lengths), jnp.asarray(toks),
+                    jnp.asarray(btab), jnp.asarray(decoding), self.glass_slots.arena,
+                    jnp.asarray(ftoks), jnp.asarray(fmask), jnp.asarray(perm),
+                    jnp.asarray(pos0), jnp.asarray(seeds), jnp.asarray(temp),
+                    jnp.asarray(topk), jnp.asarray(topp), jnp.asarray(minp),
+                    jnp.asarray(gmask), jnp.asarray(stop_ids),
+                    groups, sampled,
+                )
+        with TraceAnnotation("engine.decode.dispatch"):
+            if self._verify_parallel:
+                tgt, arena = self._pverify(*args)
+            else:
+                _, tgt, _, arena = self._decode(*args)
         self.pool.cache = arena
-        tgt = np.asarray(tgt)  # (k+1, B) target-tier verdicts
+        with TraceAnnotation("engine.decode.wait"):
+            tgt = np.asarray(tgt)  # (k+1, B) target-tier verdicts
+        with TraceAnnotation("engine.decode.commit"):
+            self._spec_accept(run, k, tgt, finished)
+
+    def _spec_accept(self, run: List[LiveRequest], k: int, tgt: np.ndarray,
+                     finished: List[RequestOutput]) -> None:
+        """The host half of :meth:`_spec_verify`: accept, roll back, fix
+        up and finish each participant against the verdicts ``tgt``."""
+        has_state = self.pool.has_state
         self.spec_ticks += 1
         self.spec_slot_ticks += len(run)
         self.spec_drafted += k * len(run)
@@ -2337,42 +2488,45 @@ class PagedEngine(_QueueEngineBase):
         earlier un-scatter does not perturb it); every other slot's table
         entry is trash-redirected and its state row is guarded by the
         decoding mask, so nothing else moves."""
-        B = self.pool.max_slots
-        decoding = np.zeros((B,), bool)
-        lengths = np.zeros((B,), np.int32)
-        toks = np.zeros((B,), np.int32)
-        ftoks = np.zeros((H, B), np.int32)
-        fmask = np.zeros((H, B), bool)
-        rows_max = 1
-        for slot, ck, accepted in group:
-            self.pool.restore_state_rows(slot, ck.state_rows)
-            decoding[slot] = True
-            lengths[slot] = ck.rows
-            toks[slot] = ck.pending
-            rows_max = max(rows_max, ck.rows + H)
-            for j in range(H - 1):
-                ftoks[j, slot] = accepted[j]
-                fmask[j, slot] = True
-        if self.pool.has_paged:
-            nb = _pow2_bucket(-(-rows_max // self.pool.block_size), self.pool.nb_max)
-            btab = np.where(
-                decoding[:, None], self.pool.block_table[:, :nb], 0
-            ).astype(np.int32)
-        else:
-            btab = np.zeros((B, 1), np.int32)
-        # sampled=False: the replay's emissions are discarded (every real
-        # feed is forced), so the greedy-compiled variant serves it
-        _, _, _, arena = self._decode(
-            self.params, self.pool.cache, jnp.asarray(lengths), jnp.asarray(toks),
-            jnp.asarray(btab), jnp.asarray(decoding), self.glass_slots.arena,
-            jnp.asarray(ftoks), jnp.asarray(fmask),
-            jnp.zeros((B,), jnp.int32),
-            jnp.zeros((B,), jnp.int32), jnp.zeros((B,), jnp.int32),
-            jnp.ones((B,), jnp.float32), jnp.zeros((B,), jnp.int32),
-            jnp.ones((B,), jnp.float32), jnp.zeros((B,), jnp.float32),
-            jnp.ones((B,), bool), jnp.full((B, MAX_STOP_IDS), -1, jnp.int32),
-            (), False,
-        )
+        with TraceAnnotation("engine.decode.prepare", H=H, rows=len(group)):
+            B = self.pool.max_slots
+            decoding = np.zeros((B,), bool)
+            lengths = np.zeros((B,), np.int32)
+            toks = np.zeros((B,), np.int32)
+            ftoks = np.zeros((H, B), np.int32)
+            fmask = np.zeros((H, B), bool)
+            rows_max = 1
+            for slot, ck, accepted in group:
+                self.pool.restore_state_rows(slot, ck.state_rows)
+                decoding[slot] = True
+                lengths[slot] = ck.rows
+                toks[slot] = ck.pending
+                rows_max = max(rows_max, ck.rows + H)
+                for j in range(H - 1):
+                    ftoks[j, slot] = accepted[j]
+                    fmask[j, slot] = True
+            if self.pool.has_paged:
+                nb = _pow2_bucket(-(-rows_max // self.pool.block_size), self.pool.nb_max)
+                btab = np.where(
+                    decoding[:, None], self.pool.block_table[:, :nb], 0
+                ).astype(np.int32)
+            else:
+                btab = np.zeros((B, 1), np.int32)
+            # sampled=False: the replay's emissions are discarded (every real
+            # feed is forced), so the greedy-compiled variant serves it
+            args = (
+                self.params, self.pool.cache, jnp.asarray(lengths), jnp.asarray(toks),
+                jnp.asarray(btab), jnp.asarray(decoding), self.glass_slots.arena,
+                jnp.asarray(ftoks), jnp.asarray(fmask),
+                jnp.zeros((B,), jnp.int32),
+                jnp.zeros((B,), jnp.int32), jnp.zeros((B,), jnp.int32),
+                jnp.ones((B,), jnp.float32), jnp.zeros((B,), jnp.int32),
+                jnp.ones((B,), jnp.float32), jnp.zeros((B,), jnp.float32),
+                jnp.ones((B,), bool), jnp.full((B, MAX_STOP_IDS), -1, jnp.int32),
+                (), False,
+            )
+        with TraceAnnotation("engine.decode.dispatch"):
+            _, _, _, arena = self._decode(*args)
         self.pool.cache = arena
 
     def _rollback_speculation(self, e: LiveRequest) -> None:
@@ -2449,12 +2603,11 @@ class PagedEngine(_QueueEngineBase):
             if not run:
                 return [], H
 
-    def _plain_decode(self, run: List[LiveRequest], H: int,
-                      finished: List[RequestOutput]) -> None:
-        """One fused H-step decode scan over ``run`` (growth already
-        ensured): per-slot sampling policy, forced replay re-feeds, and
-        in-scan stop detection — a slot whose emitted token hits its stop
-        set is truncated at the hit and finished (blocks freed) this tick."""
+    def _decode_args(self, run: List[LiveRequest], H: int) -> tuple:
+        """The decode program's arguments for one fused H-step scan over
+        ``run`` (growth already ensured): per-slot sampling policy, forced
+        replay re-feeds and the shared-list FFN grouping, copied to the
+        device.  Counts the call into the decode telemetry."""
         B = self.pool.max_slots
         decoding, lengths, toks, btab = self._scan_inputs(run, H)
         pos0, seeds, temp, topk, topp, minp, gmask, stop_ids, sampled = (
@@ -2471,10 +2624,14 @@ class PagedEngine(_QueueEngineBase):
                     ftoks[j, s] = e.outputs[start + j]
                     fmask[j, s] = True
         groups, perm = self._ffn_grouping(run)
+        # grouped rows are live by construction (_ffn_grouping keys only
+        # RUNNING slots)
+        self.grouped_rows += H * sum(groups)
+        self._count_decode(run, lengths[decoding], H, 1, btab.shape[1], groups, perm)
         if perm is None:
             perm = np.zeros((B,), np.int32)  # unused when groups == ()
         extra = self.glass_slots.arena if self.glass_slots is not None else None
-        seq, _, hits, arena = self._decode(
+        return (
             self.params, self.pool.cache, jnp.asarray(lengths), jnp.asarray(toks),
             jnp.asarray(btab), jnp.asarray(decoding), extra,
             jnp.asarray(ftoks), jnp.asarray(fmask), jnp.asarray(perm),
@@ -2483,31 +2640,37 @@ class PagedEngine(_QueueEngineBase):
             jnp.asarray(gmask), jnp.asarray(stop_ids),
             groups, sampled,
         )
+
+    def _plain_decode(self, run: List[LiveRequest], H: int, args: tuple,
+                      finished: List[RequestOutput]) -> None:
+        """One fused H-step decode scan over ``run`` with the arguments
+        :meth:`_decode_args` built: in-scan stop detection — a slot whose
+        emitted token hits its stop set is truncated at the hit and
+        finished (blocks freed) this tick."""
+        with TraceAnnotation("engine.decode.dispatch"):
+            seq, _, hits, arena = self._decode(*args)
         self.pool.cache = arena
-        seq = np.asarray(seq)  # (H, B)
-        hits = np.asarray(hits)  # (H, B) in-scan stop detections
-        self.slot_steps += H * len(run)
-        # telemetry: grouped rows are live by construction (_ffn_grouping
-        # keys only RUNNING slots); memory integrates POST-growth holdings —
-        # blocks allocated for this chunk's boundary crossings count for
-        # every tick they are held
-        self.grouped_rows += H * sum(groups)
-        for e in run:
-            s = e.slot
-            self.pool.lengths[s] += H
-            f = min(H, e.replay_left)
-            e.replay_left -= f
-            new = [int(x) for x in seq[f:, s]]
-            hit_steps = np.nonzero(hits[f:, s])[0]
-            if hit_steps.size:
-                new = new[: int(hit_steps[0]) + 1]
-            e.outputs.extend(new)
-            e.pending = int(seq[-1, s])
-            e.rng_pos = len(e.outputs)
-            if hit_steps.size:
-                self._finish(s, finished, self._stop_reason(e, e.outputs[-1]))
-            elif len(e.outputs) >= e.req.max_new:
-                self._finish(s, finished, "length")
+        with TraceAnnotation("engine.decode.wait"):
+            seq = np.asarray(seq)  # (H, B)
+            hits = np.asarray(hits)  # (H, B) in-scan stop detections
+        with TraceAnnotation("engine.decode.commit"):
+            self.slot_steps += H * len(run)
+            for e in run:
+                s = e.slot
+                self.pool.lengths[s] += H
+                f = min(H, e.replay_left)
+                e.replay_left -= f
+                new = [int(x) for x in seq[f:, s]]
+                hit_steps = np.nonzero(hits[f:, s])[0]
+                if hit_steps.size:
+                    new = new[: int(hit_steps[0]) + 1]
+                e.outputs.extend(new)
+                e.pending = int(seq[-1, s])
+                e.rng_pos = len(e.outputs)
+                if hit_steps.size:
+                    self._finish(s, finished, self._stop_reason(e, e.outputs[-1]))
+                elif len(e.outputs) >= e.req.max_new:
+                    self._finish(s, finished, "length")
 
     def _decode_tick(self, finished: List[RequestOutput], prefill_pending: bool) -> bool:
         run = self.lc.in_state(ReqState.RUNNING)
@@ -2533,19 +2696,26 @@ class PagedEngine(_QueueEngineBase):
                 if id(e) not in spec_ids
             ]
             if others:
-                others, _ = self._fit_growth(others, 1)
+                with TraceAnnotation("engine.decode.prepare") as span:
+                    others, _ = self._fit_growth(others, 1)
+                    span.set_metadata(H=1, rows=len(others))
+                    args = self._decode_args(others, 1) if others else None
                 if others:
-                    self._plain_decode(others, 1, finished)
+                    self._plain_decode(others, 1, args, finished)
             self.t += 1
             return True
-        H = self._horizon(prefill_pending)
-        run, H = self._fit_growth(run, H)
-        if not run:
-            return False
-        # memory telemetry: POST-growth holdings — blocks allocated for this
-        # chunk's boundary crossings count for every tick they are held
-        self.kv_row_ticks += H * self.pool.blocks_in_use * self.pool.block_size
-        self._plain_decode(run, H, finished)
+        with TraceAnnotation("engine.decode.prepare") as span:
+            H = self._horizon(prefill_pending)
+            run, H = self._fit_growth(run, H)
+            if not run:
+                return False
+            span.set_metadata(H=H, rows=len(run))
+            # memory telemetry: POST-growth holdings — blocks allocated for
+            # this chunk's boundary crossings count for every tick they are
+            # held
+            self.kv_row_ticks += H * self.pool.blocks_in_use * self.pool.block_size
+            args = self._decode_args(run, H)
+        self._plain_decode(run, H, args, finished)
         self.t += H
         return True
 
@@ -2562,13 +2732,19 @@ class PagedEngine(_QueueEngineBase):
         stop | eos``; :meth:`abort` returns its own), plus one live delta
         (``new_tokens``) per request that accepted tokens this tick —
         consume them as they arrive for streaming generation."""
+        with TraceAnnotation("engine.step"):
+            return self._step()
+
+    def _step(self) -> List[RequestOutput]:
         finished: List[RequestOutput] = []
         t0 = self.t
-        self._swap_in_tick()
-        self._admit_tick()
+        with TraceAnnotation("engine.admit"):
+            self._swap_in_tick()
+            self._admit_tick()
         prefilled = self._prefill_tick(finished)
-        self._swap_in_tick()  # a finished max_new==1 request frees capacity
-        self._admit_tick()
+        with TraceAnnotation("engine.admit"):
+            self._swap_in_tick()  # a finished max_new==1 request frees capacity
+            self._admit_tick()
         # memory telemetry: blocks held by every in-flight request (decoding
         # AND mid-prefill); _decode_tick charges its own ticks post-growth,
         # this snapshot covers prefill-only / idle advances
@@ -2585,11 +2761,12 @@ class PagedEngine(_QueueEngineBase):
         # streaming deltas for everything still live that grew this tick
         # (accepted tokens only: SPECULATING never persists across a tick,
         # so provisional drafts are never reported)
-        for e in self.lc.in_state(
-            ReqState.PREFILLING, ReqState.RUNNING,
-            ReqState.PREEMPTED_SWAPPED, ReqState.PREEMPTED_RECOMPUTE,
-            ReqState.MIGRATING,
-        ):
-            if len(e.outputs) > e.emitted:
-                finished.append(self._output(e, finished=False))
+        with TraceAnnotation("engine.outputs"):
+            for e in self.lc.in_state(
+                ReqState.PREFILLING, ReqState.RUNNING,
+                ReqState.PREEMPTED_SWAPPED, ReqState.PREEMPTED_RECOMPUTE,
+                ReqState.MIGRATING,
+            ):
+                if len(e.outputs) > e.emitted:
+                    finished.append(self._output(e, finished=False))
         return finished
