@@ -9,9 +9,9 @@ blocks and GLASS arenas fit one 16 GB v5e chip.
     python chip_smoke.py               # one chip: phases A and B
     python chip_smoke.py --four-chips  # four chips: cluster path only
 
-Phase A is the chip-native path: block-sparse GLASS FFN kernels (shared-list
-and rowwise, with per-request tile scales), the fused paged-attention kernel
-and self-speculative decode.  Its decode logits, taken from the live engine
+Phase A is the chip-native path: the block-sparse GLASS FFN kernel over the
+union of the rows' block lists (per-row tile scales), the fused
+paged-attention kernel and self-speculative decode.  Its decode logits, taken from the live engine
 state, are checked against the XLA path (gather attention + masked FFN).
 Phase B is the default constructor path (compact GLASS, gather attention)
 and records the size of its per-slot compact weight copies.
@@ -115,22 +115,21 @@ def drain(eng, limit=2000):
 
 def logits_check(jax, jnp, np, model, params, eng):
     """One decode step from the engine's live state, through the kernels
-    (paged Pallas attention + block-sparse FFN, shared-list where rows share
-    a block list) and through XLA (gather attention + masked FFN)."""
+    (paged Pallas attention + block-sparse FFN over the union of the rows'
+    block lists) and through XLA (gather attention + masked FFN).  Returns
+    whether the union held fewer tiles than the rows' lists together."""
     from repro.serve.lifecycle import ReqState
 
     run = eng.lc.in_state(ReqState.RUNNING)
     decoding, lengths, toks, btab = eng._scan_inputs(run, 1)
-    groups, perm = eng._ffn_grouping(run)
     arena = eng.glass_slots.arena
     idx, scale = arena["idx"], arena["scale"]
     bs = eng.glass.block_size
 
-    def kernel_path(p, cache, t, ln, bt, ix, sc, pm):
+    def kernel_path(p, cache, t, ln, bt, ix, sc):
         return model.decode_step(
             p, t[:, None], cache, ln, block_table=bt, attn_mode="paged_pallas",
             ffn_block_idx=ix, ffn_block_scale=sc, ffn_block_size=bs,
-            ffn_groups=groups or None, ffn_row_perm=pm if groups else None,
         )[0][:, 0].astype(jnp.float32)
 
     def xla_path(p, cache, t, ln, bt, ix, sc):
@@ -143,18 +142,19 @@ def logits_check(jax, jnp, np, model, params, eng):
             ffn_masks=mask,
         )[0][:, 0].astype(jnp.float32)
 
-    pm = jnp.asarray(perm if groups else np.arange(len(lengths), dtype=np.int32))
     args = (params, eng.pool.cache, jnp.asarray(toks), jnp.asarray(lengths),
             jnp.asarray(btab), idx, scale)
-    compiled = jax.jit(kernel_path).lower(*args, pm).compile()
+    compiled = jax.jit(kernel_path).lower(*args).compile()
     n_custom = compiled.as_text().count("tpu_custom_call")
-    lk = np.asarray(compiled(*args, pm))
+    lk = np.asarray(compiled(*args))
     lx = np.asarray(jax.jit(xla_path)(*args))
     rows = np.flatnonzero(decoding)
     lk, lx = lk[rows], lx[rows]
     rel = float(np.linalg.norm(lk - lx) / np.linalg.norm(lx))
-    log(f"logits check over {len(rows)} decoding rows (shared-list groups "
-        f"{groups or 'none'}): rel_l2={rel:.3e} "
+    union = int(np.logical_or.reduce([e.ffn_tiles for e in run]).sum())
+    kept = int(sum(e.ffn_tiles.sum() for e in run))
+    log(f"logits check over {len(rows)} decoding rows (union of {kept} kept "
+        f"tiles: {union}): rel_l2={rel:.3e} "
         f"max_abs={float(np.max(np.abs(lk - lx))):.3e} "
         f"max_abs_ref={float(np.max(np.abs(lx))):.3e} "
         f"argmax_agree={int(np.sum(lk.argmax(-1) == lx.argmax(-1)))}/{len(rows)} "
@@ -164,7 +164,7 @@ def logits_check(jax, jnp, np, model, params, eng):
     check(rel <= LOGIT_REL_L2_TOL,
           f"kernel vs XLA decode logits rel_l2 <= {LOGIT_REL_L2_TOL}")
     check(n_custom > 0, "compiled decode program contains tpu_custom_call")
-    return bool(groups)
+    return union < kept
 
 
 def phase_a(jax, jnp, np, model, params, prior, dev, meter):
@@ -182,9 +182,8 @@ def phase_a(jax, jnp, np, model, params, prior, dev, meter):
                       glass_mode="block_sparse", attn_mode="paged_pallas",
                       spec_k=3)
     a, b, c, d = prompts(np, V, (48, 16, 32, 64), SEED + 3)
-    # a twice: identical prompts give identical block lists, so those two
-    # rows share the shared-list kernel; the rest take the rowwise kernel.
-    # uid 2 asks for a quarter density: its dropped tiles scale to 0.0
+    # a twice: identical prompts give identical block lists, so the union
+    # of the rows' lists is smaller than the lists together.  uid 2 asks for a quarter density: its dropped tiles scale to 0.0
     reqs = [(a, None), (a, None), (b, GlassParams(density=0.25)), (c, None), (d, None)]
     for uid, (p, gp) in enumerate(reqs):
         eng.add_request(p, 32, uid=uid, glass=gp)
@@ -200,7 +199,7 @@ def phase_a(jax, jnp, np, model, params, prior, dev, meter):
         running = eng.lc.in_state(ReqState.RUNNING)
         if not checked and len(running) == len(reqs):
             shared = logits_check(jax, jnp, np, model, params, eng)
-            check(shared, "shared-list FFN kernel exercised (rows grouped)")
+            check(shared, "union FFN grid shares tiles across rows")
             checked = True
         if not eng._work_remaining():
             break

@@ -22,6 +22,12 @@ list outright, while every row still shares one fixed-width compiled grid.
 (The tiles are still streamed; per-request density trades I/O for not
 recompiling per request.  ``None`` keeps the original unscaled program.)
 
+Batched decode: the rows' lists differ, but they share most tiles.  The
+shared-list kernel then walks the UNION of the rows' kept tiles
+(``ops.ffn_union``) with a ``(nb, B)`` scale table: step ``i`` streams tile
+``idx[i]`` once and applies each row's own scale, 0.0 for a row that does
+not keep it.  Every kept tile is read from HBM once per step for all rows.
+
 VMEM budget per step (worst assigned case d = 8192, bs = 128, B <= 128):
 x 2 MiB + 3 weight tiles 6 MiB + acc 4 MiB ~= 12 MiB before the pipeline
 double-buffers the tiles.  Whether a shape fits the chip's scoped VMEM is
@@ -57,68 +63,99 @@ def _tile_contrib(x, wg_ref, wu_ref, wd_ref, *, act: str, gated: bool):
     )
 
 
-def _kernel(*refs, act: str, gated: bool, scaled: bool, rowwise: bool):
-    if scaled:
-        _, sc_ref, x_ref, wg_ref, wu_ref, wd_ref, o_ref = refs
-    else:
-        _, x_ref, wg_ref, wu_ref, wd_ref, o_ref = refs
+def _kernel(*refs, act: str, gated: bool, rowwise: bool, smem_scale: bool,
+            row_scale: bool, guarded: bool):
+    n_pre = 1 + smem_scale + guarded  # block ids [, per-step scales] [, length]
+    pre, refs = refs[:n_pre], refs[n_pre:]
+    if row_scale:
+        sc_ref, refs = refs[0], refs[1:]
+    x_ref, wg_ref, wu_ref, wd_ref, o_ref = refs
     i = pl.program_id(1 if rowwise else 0)  # position in the active list
 
     @pl.when(i == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    x = x_ref[0] if rowwise else x_ref[...]
-    contrib = _tile_contrib(x, wg_ref, wu_ref, wd_ref, act=act, gated=gated)
-    if scaled:
-        # the per-step scale lives in SMEM (scalar prefetch): a dynamic lane
-        # index into a VMEM block is not provably 128-aligned, so Mosaic
-        # refuses it
-        k = pl.program_id(0) * pl.num_programs(1) + i if rowwise else i
-        contrib = sc_ref[k] * contrib
-    o_ref[...] += contrib[None] if rowwise else contrib
+    def _accumulate():
+        x = x_ref[0] if rowwise else x_ref[...]
+        contrib = _tile_contrib(x, wg_ref, wu_ref, wd_ref, act=act, gated=gated)
+        if smem_scale:
+            # the per-step scale lives in SMEM (scalar prefetch): a dynamic
+            # lane index into a VMEM block is not provably 128-aligned, so
+            # Mosaic refuses it
+            k = pl.program_id(0) * pl.num_programs(1) + i if rowwise else i
+            contrib = pre[1][k] * contrib
+        elif row_scale:
+            contrib = sc_ref[0] * contrib  # this tile's (B, 1) column of scales
+        o_ref[...] += contrib[None] if rowwise else contrib
+
+    if guarded:  # steps past the list's real length only pad the grid
+        pl.when(i < pre[-1][0])(_accumulate)
+    else:
+        _accumulate()
 
 
-def _call(x, w_up, w_down, idx, w_gate, block_scale, *, act, block_size,
-          interpret, grid, x_block, x_map, tile):
+def _call(x, w_up, w_down, idx, w_gate, block_scale, n_active, *, act,
+          block_size, interpret, grid, x_block, x_map, tile):
     """Shared pallas_call for both kernels: ``tile(*grid_ids, idx)`` is the
     active block id a grid step streams, ``x_map`` places the x/out block.
-    The call is named ``glass_ffn_rowwise`` (a 2-D grid) or
-    ``glass_ffn_shared``: on a TPU its custom call's HLO instruction takes
-    the name, and so does the operation in a profiler trace."""
+    A 1-D ``block_scale`` rides in SMEM as one scalar per grid step; a
+    ``(nb, B)`` one (shared grid only) is a VMEM ``(1, B, 1)`` block per
+    step.  ``n_active`` (shared grid only) is the list's real length: later
+    steps repeat its last id and compute nothing.  The call is named
+    ``glass_ffn_rowwise`` (a 2-D grid) or ``glass_ffn_shared``: on a TPU its
+    custom call's HLO instruction takes the name, and so does the operation
+    in a profiler trace."""
     d = x.shape[-1]
     gated = w_gate is not None
     if not gated:  # dummy ref so the kernel signature stays uniform
         w_gate = w_up
-    scaled = block_scale is not None
+    row_scale = block_scale is not None and block_scale.ndim == 2 and len(grid) == 1
+    smem_scale = block_scale is not None and not row_scale
+    guarded = n_active is not None
     n = len(grid)
     # index maps receive every scalar-prefetch ref; only the block ids steer
     col = lambda *a: (0, tile(*a[:n], a[n]))
     row = lambda *a: (tile(*a[:n], a[n]), 0)
     xm = lambda *a: x_map(*a[:n])
+    in_specs = [
+        pl.BlockSpec(x_block, xm),  # x: resident
+        pl.BlockSpec((d, block_size), col),  # w_gate tile
+        pl.BlockSpec((d, block_size), col),  # w_up tile
+        pl.BlockSpec((block_size, d), row),  # w_down tile
+    ]
+    scalars = (idx,)
+    if smem_scale:
+        scalars += (jnp.asarray(block_scale, jnp.float32).reshape(idx.shape),)
+    if guarded:
+        scalars += (jnp.asarray(n_active, jnp.int32).reshape(1),)
+    operands = (x, w_gate, w_up, w_down)
+    if row_scale:
+        nb, B = block_scale.shape
+
+        def sc_map(i, *pre):
+            if guarded:  # padded steps keep the last real block: no fetch
+                i = jnp.maximum(jnp.minimum(i, pre[-1][0] - 1), 0)
+            return (i, 0, 0)
+
+        # the last two block dims equal the array's, which Mosaic accepts
+        in_specs.insert(0, pl.BlockSpec((1, B, 1), sc_map))
+        operands = (jnp.asarray(block_scale, jnp.float32).reshape(nb, B, 1),) + operands
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2 if scaled else 1, grid=grid,
-        in_specs=[
-            pl.BlockSpec(x_block, xm),  # x: resident
-            pl.BlockSpec((d, block_size), col),  # w_gate tile
-            pl.BlockSpec((d, block_size), col),  # w_up tile
-            pl.BlockSpec((block_size, d), row),  # w_down tile
-        ],
+        num_scalar_prefetch=len(scalars), grid=grid, in_specs=in_specs,
         out_specs=pl.BlockSpec(x_block, xm),
     )
     fn = pl.pallas_call(
         functools.partial(
-            _kernel, act=act, gated=gated, scaled=scaled, rowwise=n == 2
+            _kernel, act=act, gated=gated, rowwise=n == 2, smem_scale=smem_scale,
+            row_scale=row_scale, guarded=guarded,
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(x.shape, jnp.float32),
         interpret=interpret,
         name="glass_ffn_rowwise" if n == 2 else "glass_ffn_shared",
     )
-    scalars = (idx,)
-    if scaled:
-        scalars += (jnp.asarray(block_scale, jnp.float32).reshape(idx.shape),)
-    return fn(*scalars, x, w_gate, w_up, w_down)
+    return fn(*scalars, *operands)
 
 
 def glass_ffn_block_sparse(
@@ -128,16 +165,28 @@ def glass_ffn_block_sparse(
     block_idx: jax.Array,  # (nb_active,) int32 — active block ids
     w_gate: jax.Array | None = None,  # (d, m)
     *,
-    block_scale: jax.Array | None = None,  # (nb_active,) f32 tile multipliers
+    block_scale: jax.Array | None = None,  # (nb_active,) or (nb_active, B) f32
+    n_active: jax.Array | None = None,  # int32 scalar: real length of block_idx
     act: str = "silu",
     block_size: int = 128,
     interpret: bool = False,
 ) -> jax.Array:
-    """Returns (B, d) f32. Only active weight blocks are read from HBM."""
+    """Returns (B, d) f32. Only active weight blocks are read from HBM, each
+    once for all B rows.
+
+    ``block_scale`` of shape ``(nb_active,)`` multiplies a tile's
+    contribution for every row; ``(nb_active, B)`` gives each row its own
+    multiplier, so one list can be the union of several rows' lists (see
+    ``ops.ffn_union``): a row's 0.0 on a tile it does not keep adds exactly
+    nothing, and each row sums its own tiles in list order.  With
+    ``n_active``, grid steps at or past it compute nothing; the list pads
+    them by repeating its last real id, so they fetch nothing either."""
     B, d = x.shape
     assert w_up.shape[1] % block_size == 0, (w_up.shape, block_size)
+    if block_scale is not None and block_scale.ndim == 2:
+        assert block_scale.shape == (block_idx.shape[0], B), (block_scale.shape, B)
     return _call(
-        x, w_up, w_down, block_idx, w_gate, block_scale, act=act,
+        x, w_up, w_down, block_idx, w_gate, block_scale, n_active, act=act,
         block_size=block_size, interpret=interpret, grid=(block_idx.shape[0],),
         x_block=(B, d), x_map=lambda i: (0, 0), tile=lambda i, idx: idx[i],
     )
@@ -164,11 +213,11 @@ def glass_ffn_block_sparse_rowwise(
     sequential grid).  Rows travel as ``(B, 1, d)`` so that a row's block
     spans the array's last two dims (Mosaic's (8, 128) rule), and the block
     lists and scales as flat ``B * nb`` SMEM vectors.  Rows are processed
-    independently — batching rows that share a block list into the
-    shared-list kernel is a further optimization the engine can apply when
-    masks collide.  ``block_scale`` multiplies row b's i-th tile
-    contribution (per-request GLASS density nested inside the capacity-tier
-    list; 0.0 exactly drops a tile).  Returns (B, d) f32.
+    independently, so a tile that two rows keep is streamed twice; the
+    serving decode streams the union of the rows' lists once through
+    :func:`glass_ffn_block_sparse` instead.  ``block_scale`` multiplies row
+    b's i-th tile contribution (per-request GLASS density nested inside the
+    capacity-tier list; 0.0 exactly drops a tile).  Returns (B, d) f32.
     """
     B, d = x.shape
     assert w_up.shape[1] % block_size == 0, (w_up.shape, block_size)
@@ -178,7 +227,7 @@ def glass_ffn_block_sparse_rowwise(
         assert block_scale.shape == block_idx.shape, (block_scale.shape, block_idx.shape)
     out = _call(
         x.reshape(B, 1, d), w_up, w_down, block_idx.astype(jnp.int32).reshape(B * nb),
-        w_gate, block_scale, act=act, block_size=block_size, interpret=interpret,
+        w_gate, block_scale, None, act=act, block_size=block_size, interpret=interpret,
         grid=(B, nb), x_block=(1, 1, d), x_map=lambda b, i: (b, 0, 0),
         tile=lambda b, i, idx: idx[b * nb + i],
     )

@@ -1,4 +1,5 @@
-"""Jit'd public entry points for the Pallas kernels.
+"""Jit'd public entry points for the Pallas kernels, and ``ffn_union``, which
+turns per-row FFN block lists into the one list the batched decode streams.
 
 ``interpret=None`` (the default) is decided when the kernel is lowered, for
 the platform it is lowered for: the Pallas interpreter for a CPU (where the
@@ -11,6 +12,7 @@ from __future__ import annotations
 from functools import partial
 
 import jax
+import jax.numpy as jnp
 
 from .flash_attention import flash_attention as _flash
 from .glass_ffn import glass_ffn_block_sparse as _glass_ffn
@@ -31,17 +33,60 @@ def _by_platform(kernel, args, interpret):
 
 @partial(jax.jit, static_argnames=("act", "block_size", "interpret"))
 def glass_ffn(
-    x, w_up, w_down, block_idx, w_gate=None, *, block_scale=None, act="silu",
-    block_size=128, interpret=None,
+    x, w_up, w_down, block_idx, w_gate=None, *, block_scale=None, n_active=None,
+    act="silu", block_size=128, interpret=None,
 ):
-    """Block-sparse GLASS FFN decode step: only active weight blocks are read."""
-    def kernel(x, w_up, w_down, block_idx, w_gate, block_scale, interpret):
+    """Block-sparse GLASS FFN decode step: only active weight blocks are read,
+    each once for all rows.  ``block_scale`` (nb,) scales every row alike,
+    (nb, B) each row on its own; ``n_active`` is the list's real length
+    (the union from :func:`ffn_union`)."""
+    def kernel(x, w_up, w_down, block_idx, w_gate, block_scale, n_active, interpret):
         return _glass_ffn(
             x, w_up, w_down, block_idx, w_gate, block_scale=block_scale,
-            act=act, block_size=block_size, interpret=interpret,
+            n_active=n_active, act=act, block_size=block_size, interpret=interpret,
         )
 
-    return _by_platform(kernel, (x, w_up, w_down, block_idx, w_gate, block_scale), interpret)
+    return _by_platform(
+        kernel, (x, w_up, w_down, block_idx, w_gate, block_scale, n_active), interpret
+    )
+
+
+def ffn_union(block_idx, block_scale=None, rows=None, *, n_tiles: int):
+    """Per layer, the union of the rows' FFN block lists, as the shared-list
+    ``glass_ffn`` walks it.
+
+    ``block_idx`` / ``block_scale`` are (L, B, nb) per-row lists and tile
+    multipliers (``None``: all 1.0); ``rows`` (B,) bool marks the rows that
+    decode (``None``: all).  Returns
+
+      * ``ids`` (L, n_tiles) int32: the tiles some decoding row keeps with
+        scale > 0, ascending, padded by repeating the last one (0 if none);
+      * ``count`` (L,) int32: how many of ``ids`` are real;
+      * ``scale`` (L, n_tiles, B) f32: at union position p, each row's scale
+        of tile ``ids[:, p]`` where the row keeps it, 0.0 elsewhere, on
+        padded positions and on rows that do not decode.
+
+    The width is fixed at ``n_tiles``, so a decode program has one shape."""
+    L, B, _ = block_idx.shape
+    if block_scale is None:
+        block_scale = jnp.ones(block_idx.shape, jnp.float32)
+    scale = block_scale.astype(jnp.float32)
+    if rows is not None:
+        scale = jnp.where(rows[None, :, None], scale, 0.0)
+    tiles = jnp.arange(n_tiles, dtype=jnp.int32)
+    # (L, B, n_tiles): a row's scale of each tile (lists hold distinct ids)
+    table = jnp.sum(
+        jnp.where(block_idx[..., None] == tiles, scale[..., None], 0.0), axis=2
+    )
+    kept = jnp.any(table > 0.0, axis=1)  # (L, n_tiles)
+    count = jnp.sum(kept, axis=-1, dtype=jnp.int32)
+    order = jnp.argsort(~kept, axis=-1, stable=True).astype(jnp.int32)
+    pos = jnp.minimum(tiles[None], jnp.maximum(count - 1, 0)[:, None])
+    ids = jnp.take_along_axis(order, pos, axis=-1)
+    per_pos = jnp.take_along_axis(table, jnp.broadcast_to(ids[:, None], (L, B, n_tiles)),
+                                  axis=-1)
+    per_pos = jnp.where(tiles[None, None] < count[:, None, None], per_pos, 0.0)
+    return ids, count, per_pos.swapaxes(1, 2)
 
 
 @partial(jax.jit, static_argnames=("act", "block_size", "interpret"))

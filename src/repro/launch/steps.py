@@ -209,44 +209,25 @@ def make_decode_step_masked(model: Model, attn_mode: str = "gather"):
     return decode
 
 
-def make_decode_step_block_sparse(model: Model, block_size: int, groups=None,
+def make_decode_step_block_sparse(model: Model, block_size: int,
                                   attn_mode: str = "gather"):
     """Block-sparse decode: per-request active FFN block ids (from
     ``GlassConfig(selection="block")``) feed the pallas ``glass_ffn`` kernel
     directly — weights stay resident, only active (d x block_size) tiles are
     streamed.  ``block_idx`` is (L, nb_keep) shared or (L, B, nb_keep)
-    per-slot (continuous batching).
+    per-slot (continuous batching); per-slot lists run as one grid over
+    their union, each kept tile streamed once for all rows."""
 
-    ``groups`` (a static tuple of sizes >= 2) lowers the *shared-list
-    batched* variant the paged engine uses when several decode rows carry
-    identical active-block lists: grouped rows run one shared-list kernel
-    per group (weight tiles streamed once per group, not once per row) and
-    the returned step takes an extra ``row_perm`` (B,) argument ordering
-    rows group-major with singletons last."""
-
-    if groups is None:
-        def decode(params, cache, token, cache_len, block_idx):
-            logits, cache = model.decode_step(
-                params, token, cache, cache_len,
-                ffn_block_idx=block_idx, ffn_block_size=block_size,
-                attn_mode=attn_mode,
-            )
-            nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)[:, None]
-            return nxt, cache
-
-        return decode
-
-    def decode_grouped(params, cache, token, cache_len, block_idx, row_perm):
+    def decode(params, cache, token, cache_len, block_idx):
         logits, cache = model.decode_step(
             params, token, cache, cache_len,
             ffn_block_idx=block_idx, ffn_block_size=block_size,
-            ffn_groups=tuple(groups), ffn_row_perm=row_perm,
             attn_mode=attn_mode,
         )
         nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)[:, None]
         return nxt, cache
 
-    return decode_grouped
+    return decode
 
 
 def make_verify_step(model: Model, glass_mode: Optional[str] = None,

@@ -135,16 +135,13 @@ class Model:
         ffn_block_idx=None,  # active FFN block ids -> block-sparse pallas kernel
         ffn_block_size: int = 128,
         ffn_block_scale=None,  # per-(row, tile) f32 multipliers (per-request density)
-        ffn_groups=None,  # static tuple: rows sharing a block list, batched
-        # through the shared-list kernel (see dense_decode_step)
-        ffn_row_perm=None,  # (B,) int32 row permutation matching ffn_groups
+        ffn_block_count=None,  # (L,) int32: ffn_block_idx is the slots' union
+        # (kernels.ops.ffn_union), computed once by a multi-step caller
         attn_mode: str = "gather",  # "paged_pallas" = fused paged-attention kernel
     ):
         cfg = self.cfg
         if ffn_block_idx is not None and cfg.family not in ("dense", "vlm"):
             raise NotImplementedError("block-sparse decode targets dense-FFN families")
-        if ffn_groups and ffn_block_idx is None:
-            raise ValueError("ffn_groups requires ffn_block_idx (block-sparse decode)")
         if cfg.is_encoder_decoder:
             return encdec.encdec_decode_step(
                 params, token, cache, cache_len, cfg, ffn_masks=ffn_masks, compact_layers=compact_layers
@@ -169,8 +166,7 @@ class Model:
             params, token, cache, cache_len, cfg, ffn_masks=ffn_masks,
             compact_layers=compact_layers, block_table=block_table,
             ffn_block_idx=ffn_block_idx, ffn_block_size=ffn_block_size,
-            ffn_block_scale=ffn_block_scale,
-            ffn_groups=ffn_groups, ffn_row_perm=ffn_row_perm,
+            ffn_block_scale=ffn_block_scale, ffn_block_count=ffn_block_count,
             attn_mode=attn_mode,
         )
 
@@ -232,11 +228,19 @@ class Model:
 
         Returns ``(verdicts (B, T) int32, cache)``.
         """
+        ffn_block_count = None
+        if ffn_block_idx is not None and ffn_block_idx.ndim == 3:
+            # per-slot lists: one union for every position (decode_step)
+            from ..kernels.ops import ffn_union
+
+            ffn_block_idx, ffn_block_count, ffn_block_scale = ffn_union(
+                ffn_block_idx, ffn_block_scale, n_tiles=self.cfg.d_ff // ffn_block_size
+            )
         kw = dict(
             ffn_masks=ffn_masks, compact_layers=compact_layers,
             block_table=block_table, ffn_block_idx=ffn_block_idx,
             ffn_block_size=ffn_block_size, ffn_block_scale=ffn_block_scale,
-            attn_mode=attn_mode,
+            ffn_block_count=ffn_block_count, attn_mode=attn_mode,
         )
         cache_len = jnp.asarray(cache_len, jnp.int32)
         sampled = seeds is not None

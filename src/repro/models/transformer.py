@@ -371,22 +371,27 @@ def dense_decode_step(
     ffn_block_idx=None,  # (L, nb_keep) shared or (L, B, nb_keep) per-slot active
     # FFN block ids -> block-sparse pallas kernel instead of dense masked matmuls
     ffn_block_size: int = 128,
-    ffn_block_scale=None,  # (L, B, nb_keep) f32 per-(row, tile) contribution
-    # multiplier (per-request density nested inside the capacity-tier lists;
-    # 0.0 exactly zeroes a padding tile).  None = all tiles at full weight.
-    ffn_groups=None,  # STATIC tuple of group sizes (each >= 2): rows whose
-    # per-slot block lists are identical, batched through the shared-list
-    # glass_ffn kernel; remaining rows run rowwise.  Requires ffn_row_perm.
-    ffn_row_perm=None,  # (B,) int32: rows reordered group-major, singletons last
+    ffn_block_scale=None,  # f32 tile multipliers matching ffn_block_idx:
+    # (L, nb_keep) shared or (L, B, nb_keep) per-slot (per-request density
+    # nested inside the capacity-tier lists; 0.0 exactly zeroes a padding
+    # tile); (L, n_tiles, B) per-row with ffn_block_count.  None = all 1.0
+    ffn_block_count=None,  # (L,) int32: ffn_block_idx (L, n_tiles) is already
+    # the union of the slots' lists (kernels.ops.ffn_union) with this many
+    # real ids, and ffn_block_scale its (L, n_tiles, B) per-row table
     attn_mode: str = "gather",
 ):
     """One decode step across all layers (scan). Returns (logits, new_cache).
 
     ``T > 1`` tokens run every position through one forward with the causal
-    intra-chunk attention mask — the parallel speculative verify.  The
-    block-sparse FFN then flattens the ``(B, T)`` grid to ``B*T`` rows
-    (each slot's block list repeated per token) so the per-row kernels
-    apply unchanged; ``T = 1`` keeps today's exact code path.
+    intra-chunk attention mask — the parallel speculative verify.
+
+    Per-slot block lists run as ONE shared-list ``glass_ffn`` grid over
+    their union per layer, each row under its own scale column, so a tile
+    that several rows keep is read once per step.  A caller that decodes
+    several steps from the same lists computes the union once
+    (``ffn_block_count``); otherwise it is computed here, over every row.
+    With ``T > 1`` the grid takes all ``B*T`` rows at once, each slot's
+    scale column repeated per token.
     """
     x = embed_tokens(params, token, cfg)
     windows = layer_windows(cfg)
@@ -395,7 +400,7 @@ def dense_decode_step(
         raise NotImplementedError("block-sparse decode targets dense-FFN families")
 
     def body(x, xs):
-        lp, ck, cv, window, mask_l, comp_l, bidx_l, bscale_l = xs
+        lp, ck, cv, window, mask_l, comp_l, bidx_l, bscale_l, cnt_l = xs
         h = rms_norm(x, lp["ln1"], cfg.norm_eps, plus_one)
         if block_table is not None:
             a, ck, cv = attention_decode_paged(
@@ -415,64 +420,18 @@ def dense_decode_step(
             mp = comp_l if comp_l is not None else lp["moe"]
             y, _, _ = moe_forward(mp, h2, cfg, mask=mask_l)
         elif bidx_l is not None:
-            from ..kernels.ops import glass_ffn, glass_ffn_rowwise
+            from ..kernels.ops import glass_ffn
 
             fp = lp["ffn"]
             B_, T_ = h2.shape[0], h2.shape[1]
-            if bidx_l.ndim == 2 and ffn_groups:
-                # shared-list batching: rows whose active-block lists are
-                # identical share ONE grid over the list (weight tiles are
-                # streamed once per group, not once per row); leftover
-                # singleton rows take the rowwise kernel in a single call
-                if T_ == 1:
-                    xb, bi, bsc, perm = h2[:, 0], bidx_l, bscale_l, ffn_row_perm
-                    groups = ffn_groups
-                else:  # flatten (B, T) -> B*T rows, lists repeated per token
-                    xb = h2.reshape(B_ * T_, -1)
-                    bi = jnp.repeat(bidx_l, T_, axis=0)
-                    bsc = None if bscale_l is None else jnp.repeat(bscale_l, T_, axis=0)
-                    steps = jnp.arange(T_, dtype=ffn_row_perm.dtype)[None]
-                    perm = (ffn_row_perm[:, None] * T_ + steps).reshape(-1)
-                    groups = tuple(g * T_ for g in ffn_groups)
-                xp = xb[perm]
-                bp = bi[perm]
-                sp = None if bsc is None else bsc[perm]
-                parts = []
-                off = 0
-                for gs in groups:
-                    parts.append(glass_ffn(
-                        xp[off : off + gs], fp["w_up"], fp["w_down"],
-                        bp[off], fp.get("w_gate"),
-                        block_scale=None if sp is None else sp[off],
-                        act=cfg.ffn_act, block_size=ffn_block_size,
-                    ))
-                    off += gs
-                if off < xp.shape[0]:
-                    parts.append(glass_ffn_rowwise(
-                        xp[off:], fp["w_up"], fp["w_down"], bp[off:],
-                        fp.get("w_gate"),
-                        block_scale=None if sp is None else sp[off:],
-                        act=cfg.ffn_act, block_size=ffn_block_size,
-                    ))
-                yp = jnp.concatenate(parts, axis=0)
-                y32 = jnp.zeros_like(yp).at[perm].set(yp)
-            else:
-                per_row = bidx_l.ndim == 2
-                kernel = glass_ffn_rowwise if per_row else glass_ffn
-                if T_ == 1:
-                    xb, bi, bsc = h2[:, 0], bidx_l, bscale_l
-                else:
-                    xb = h2.reshape(B_ * T_, -1)
-                    bi = jnp.repeat(bidx_l, T_, axis=0) if per_row else bidx_l
-                    bsc = (
-                        None if bscale_l is None
-                        else jnp.repeat(bscale_l, T_, axis=0) if per_row
-                        else bscale_l
-                    )
-                y32 = kernel(
-                    xb, fp["w_up"], fp["w_down"], bi, fp.get("w_gate"),
-                    block_scale=bsc, act=cfg.ffn_act, block_size=ffn_block_size,
-                )
+            bsc = bscale_l
+            if cnt_l is not None and T_ > 1:  # the union's (n_tiles, B) table
+                bsc = jnp.repeat(bscale_l, T_, axis=1)
+            y32 = glass_ffn(
+                h2.reshape(B_ * T_, -1), fp["w_up"], fp["w_down"], bidx_l,
+                fp.get("w_gate"), block_scale=bsc, n_active=cnt_l,
+                act=cfg.ffn_act, block_size=ffn_block_size,
+            )
             y = y32.astype(x.dtype).reshape(B_, T_, -1)
         else:
             fp = comp_l if comp_l is not None else lp["ffn"]
@@ -485,28 +444,36 @@ def dense_decode_step(
         return x, (ck, cv)
 
     L = cfg.n_layers
+    if ffn_block_idx is not None and ffn_block_idx.ndim == 3:  # per-slot lists
+        from ..kernels.ops import ffn_union
+
+        ffn_block_idx, ffn_block_count, ffn_block_scale = ffn_union(
+            ffn_block_idx, ffn_block_scale, n_tiles=cfg.d_ff // ffn_block_size
+        )
     have_mask = ffn_masks is not None
     have_comp = compact_layers is not None
     have_bidx = ffn_block_idx is not None
     have_bscale = ffn_block_scale is not None
+    have_cnt = ffn_block_count is not None
     mask_xs = ffn_masks if have_mask else jnp.zeros((L, 0))
     comp_xs = compact_layers if have_comp else jnp.zeros((L, 0))
     bidx_xs = ffn_block_idx if have_bidx else jnp.zeros((L, 0))
     bscale_xs = ffn_block_scale if have_bscale else jnp.zeros((L, 0))
+    cnt_xs = ffn_block_count if have_cnt else jnp.zeros((L, 0))
 
     def body_wrap(x, xs):
-        lp, ck, cv, window, mask_l, comp_l, bidx_l, bscale_l = xs
+        lp, ck, cv, window, mask_l, comp_l, bidx_l, bscale_l, cnt_l = xs
         return body(
             x,
             (lp, ck, cv, window, mask_l if have_mask else None,
              comp_l if have_comp else None, bidx_l if have_bidx else None,
-             bscale_l if have_bscale else None),
+             bscale_l if have_bscale else None, cnt_l if have_cnt else None),
         )
 
     x, (ck, cv) = jax.lax.scan(
         body_wrap, x,
         (params["layers"], cache["k"], cache["v"], windows, mask_xs, comp_xs,
-         bidx_xs, bscale_xs),
+         bidx_xs, bscale_xs, cnt_xs),
     )
     x = rms_norm(x, params["final_norm"], cfg.norm_eps, cfg.sandwich_norms)
     logits = lm_logits(params, x, cfg)

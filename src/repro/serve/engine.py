@@ -41,7 +41,7 @@ import itertools
 import warnings
 from dataclasses import dataclass, replace
 from functools import partial
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -58,6 +58,7 @@ from ..core.glass import (
     restore_stat_sums,
     snapshot_stat_sums,
 )
+from ..kernels.ops import ffn_union
 from ..models.api import Model
 from ..models.transformer import layer_windows
 from .kv_pool import (
@@ -433,8 +434,8 @@ class GlassSlotState:
         """Fuse stats -> per-slot rows (both tiers when ``draft_ratio`` is
         set), scatter them into the arena(s), and return the freshly built
         TARGET rows (slot axis length ``len(slots)``) so the engine can
-        derive host-side keys (e.g. active-block lists for the shared-list
-        kernel grouping) without re-reading the arena.
+        keep host-side copies (e.g. the active-block lists behind its FFN
+        tile counters) without re-reading the arena.
 
         ``overrides`` (optional, one entry per slot) carries a request's
         ``(density, draft_density)`` when it differs from the engine
@@ -538,7 +539,7 @@ class MigrationTicket:
     prefill_pos: int
     mid_prefill: bool
     glass_rows: Any = None  # host copy of GlassSlotState.save(slot), or None
-    glass_key: Optional[bytes] = None  # block_sparse decode grouping key
+    glass_key: Optional[bytes] = None  # block_sparse block ids + tile scales
     pstats: Any = None  # host stat-sum snapshot (mid-prefill tickets only)
 
 
@@ -679,6 +680,7 @@ class ContinuousEngine(_QueueEngineBase):
         self.decode_chunk = max(1, decode_chunk)
 
         bsz = glass.block_size if glass is not None else 128
+        n_tiles = model.cfg.d_ff // bsz
 
         def dec(pr, cache, lengths, toks, extra, rng, H):
             kw = {}
@@ -687,8 +689,13 @@ class ContinuousEngine(_QueueEngineBase):
             elif mode == "compact":
                 kw["compact_layers"] = extra
             elif mode == "block_sparse":
-                kw["ffn_block_idx"] = extra["idx"]
-                kw["ffn_block_scale"] = extra["scale"]
+                # one union per call: every scan step decodes the same lists
+                # (cleared slots hold 0.0 scales and drop out of it)
+                ids, count, scale = ffn_union(extra["idx"], extra["scale"],
+                                              n_tiles=n_tiles)
+                kw["ffn_block_idx"] = ids
+                kw["ffn_block_scale"] = scale
+                kw["ffn_block_count"] = count
                 kw["ffn_block_size"] = bsz
 
             def body(carry, _):
@@ -800,31 +807,16 @@ class ContinuousEngine(_QueueEngineBase):
 # ---------------------------------------------------------------------------
 
 
-def ffn_tile_fetches(ids: np.ndarray, groups: Tuple[int, ...] = (),
-                     perm: Optional[np.ndarray] = None, T: int = 1) -> int:
-    """Weight-tile fetches of one decode step's ``glass_ffn`` calls, summed
-    over layers.  ``ids`` (B, L, nb) is each slot's block list as the
-    kernels receive it (zeros where the arena row is cleared); ``groups``
-    and ``perm`` are the decode call's shared-list batching.  A shared-list
-    call streams its group's one list; the rowwise call walks its rows'
-    lists in grid order, and a grid step fetches only when its tile id
-    differs from the previous step's (the pipeline skips an unchanged
-    block).  ``T`` queries per slot repeat each slot's rows."""
-    rows = ids if T == 1 else np.repeat(ids, T, axis=0)
-    L = ids.shape[1]
-    fetches = 0
-    if groups:
-        order = (np.asarray(perm)[:, None] * T + np.arange(T)).reshape(-1)
-        off = 0
-        for g in groups:
-            one = rows[order[off]]  # (L, nb): the group's list
-            fetches += L + int(np.count_nonzero(one[:, 1:] != one[:, :-1]))
-            off += g * T
-        rows = rows[order[off:]]
-    if len(rows):  # the rowwise call: within each list, then across rows
-        fetches += L + int(np.count_nonzero(rows[:, :, 1:] != rows[:, :, :-1]))
-        fetches += int(np.count_nonzero(rows[1:, :, 0] != rows[:-1, :, -1]))
-    return fetches
+def ffn_tile_fetches(tiles: Sequence[np.ndarray]) -> int:
+    """Weight-tile fetches of one decode step's ``glass_ffn`` grids, summed
+    over layers.  ``tiles`` holds each decoding row's (L, n_tiles) bool map
+    of the tiles it keeps at scale > 0.  Each layer's grid walks the union
+    of those maps (``kernels.ops.ffn_union``) and streams each of its tiles
+    once for all rows and query positions; its padding steps repeat the
+    last id and fetch nothing, but an empty union still fetches the one
+    tile its first step names."""
+    union = np.logical_or.reduce(list(tiles))
+    return int(np.maximum(union.sum(-1), 1).sum())
 
 
 def attn_live_blocks(lengths: np.ndarray, steps: int, windows, block_size: int) -> int:
@@ -874,10 +866,10 @@ class PagedEngine(_QueueEngineBase):
         fused mask is built once, at the final chunk.
       * **decode** — one jitted step over the fixed ``max_slots`` decode
         batch reading through the block table, gather width bucketed to
-        the longest active request.  In ``block_sparse`` mode, rows whose
-        active-block lists coincide are batched through the shared-list
-        ``glass_ffn`` kernel (group-by on the host-side block-id tuples);
-        singleton rows fall back to ``glass_ffn_rowwise``.
+        the longest active request.  In ``block_sparse`` mode each decode
+        call merges the decoding rows' block lists into one union per
+        layer, on the device, and the shared-list ``glass_ffn`` kernel
+        streams each tile of it once for all rows, under per-row scales.
       * **admission** — ``AdmissionPolicy`` (FIFO / priority / deadline),
         best-effort under block availability net of the watermark reserve
         and the blocks owed to swapped-out requests awaiting swap-in.
@@ -1036,7 +1028,6 @@ class PagedEngine(_QueueEngineBase):
         self.migrations_out = 0
         self.migrations_in = 0
         self.migration_bytes = 0  # wire bytes exported by migrate_out
-        self.grouped_rows = 0  # decode row-ticks served by the shared-list kernel
         # decode waste, summed over decode steps and layers (``counters``):
         # the tile fetches the glass_ffn grids make against the distinct
         # tiles the decoding rows keep, and the blocks the attention walks
@@ -1106,28 +1097,32 @@ class PagedEngine(_QueueEngineBase):
         # (the per-slot early-finish stop set, -1 padded).  ``sampled``
         # is the only policy static: an all-greedy batch compiles without
         # any sampling ops, preserving the PR-4 greedy program exactly.
-        def mk_kw(extra, btab, perm, groups):
+        n_tiles = model.cfg.d_ff // bsz
+
+        def mk_kw(extra, btab, dmask):
             kw = {}
             if mode == "masked":
                 kw["ffn_masks"] = extra
             elif mode == "compact":
                 kw["compact_layers"] = extra
             elif mode == "block_sparse":
-                kw["ffn_block_idx"] = extra["idx"]
-                kw["ffn_block_scale"] = extra["scale"]
+                # the union of the decoding rows' lists, once per call (every
+                # step of it decodes the same lists): each kept tile is read
+                # once a step for all rows
+                ids, count, scale = ffn_union(extra["idx"], extra["scale"], dmask,
+                                              n_tiles=n_tiles)
+                kw["ffn_block_idx"] = ids
+                kw["ffn_block_scale"] = scale
+                kw["ffn_block_count"] = count
                 kw["ffn_block_size"] = bsz
-                if groups:  # shared-list batching: rows with identical lists
-                    kw["ffn_groups"] = groups
-                    kw["ffn_row_perm"] = perm
             if has_paged:
                 kw["block_table"] = btab
                 kw["attn_mode"] = attn_mode
             return kw
 
         def dec(pr, arena, lengths, toks, btab, dmask, extra, ftoks, fmask,
-                perm, pos0, seeds, temp, topk, topp, minp, gmask, stop_ids,
-                groups, sampled):
-            kw = mk_kw(extra, btab, perm, groups)
+                pos0, seeds, temp, topk, topp, minp, gmask, stop_ids, sampled):
+            kw = mk_kw(extra, btab, dmask)
 
             def guard(old, new, ax, pg):
                 # recurrent-state rows of non-decoding slots (free, or holding
@@ -1191,7 +1186,7 @@ class PagedEngine(_QueueEngineBase):
         # the arena is dead after each call — donate so the block pool (and
         # state rows) update in place instead of copying every tick
         self._decode = self.programs.register(
-            "decode", dec, static_argnums=(18, 19), donate_argnums=(1,)
+            "decode", dec, static_argnums=(17,), donate_argnums=(1,)
         )
 
         # the parallel speculative verify: every feed of a verify round is
@@ -1202,9 +1197,9 @@ class PagedEngine(_QueueEngineBase):
         # own grid program, so logits — and therefore verdicts and the KV
         # rows the round scatters — are BIT-identical to the sequential path
         # (the speculative state-invariant suite asserts it).
-        def pver(pr, arena, lengths, feed, btab, extra, perm, pos0, seeds,
-                 temp, topk, topp, minp, gmask, groups, sampled):
-            kw = mk_kw(extra, btab, perm, groups)
+        def pver(pr, arena, lengths, feed, btab, dmask, extra, pos0, seeds,
+                 temp, topk, topp, minp, gmask, sampled):
+            kw = mk_kw(extra, btab, dmask)
             lg, arena = model.decode_step(pr, feed, arena, lengths, **kw)
             lg = lg.astype(jnp.float32)  # (B, T, V)
             greedy = jnp.argmax(lg, axis=-1).astype(jnp.int32)
@@ -1224,7 +1219,7 @@ class PagedEngine(_QueueEngineBase):
             return verdict.swapaxes(0, 1), arena  # verdicts (k+1, B)
 
         self._pverify = self.programs.register(
-            "verify_parallel", pver, static_argnums=(14, 15), donate_argnums=(1,)
+            "verify_parallel", pver, static_argnums=(14,), donate_argnums=(1,)
         )
 
         axes, paged = self.pool.axes, self.pool.paged
@@ -2052,10 +2047,8 @@ class PagedEngine(_QueueEngineBase):
             )
             if self._mode == "block_sparse":
                 # host copy of the (L, nb_keep) active-block list AND its
-                # tile scales: the group-by key for the shared-list decode
-                # kernel — rows may only batch through one shared grid when
-                # both their lists and their per-request density scales
-                # coincide — and the tiles it keeps, for the waste counters
+                # tile scales, and the tiles it keeps at scale > 0, for the
+                # waste counters
                 e.glass_key = (
                     np.asarray(rows["idx"][:, 0]).tobytes()
                     + np.asarray(rows["scale"][:, 0]).tobytes()
@@ -2117,37 +2110,6 @@ class PagedEngine(_QueueEngineBase):
             for e in run
         )
 
-    def _ffn_grouping(self, run: List[LiveRequest]):
-        """Group decode rows by identical active-block lists (block_sparse
-        mode): rows in a group >= 2 batch through the shared-list
-        ``glass_ffn`` kernel; everything else (singletons, inactive and
-        mid-prefill rows) falls back to rowwise.  Returns (static group
-        sizes, row permutation) or ((), None)."""
-        if self._mode != "block_sparse":
-            return (), None
-        keys: List[Optional[bytes]] = [None] * self.pool.max_slots
-        for e in run:
-            keys[e.slot] = e.glass_key
-        groups: Dict[bytes, List[int]] = {}
-        for s in range(self.pool.max_slots):
-            if keys[s] is not None:  # inactive rows never justify a group:
-                # their output is discarded, and letting them form one would
-                # change the static `groups` signature (and recompile the
-                # decode scan) on every occupancy change
-                groups.setdefault(keys[s], []).append(s)
-        multi = [g for g in groups.values() if len(g) > 1]
-        if not multi:
-            return (), None
-        # canonicalize: sizes sorted descending, so tick-to-tick reshuffles
-        # that only permute the groups reuse one compiled decode variant —
-        # the static-signature space is partitions of max_slots (22 at 8
-        # slots), not compositions (128)
-        multi.sort(key=lambda g: (-len(g), g[0]))
-        in_multi = {s for g in multi for s in g}
-        rest = [s for s in range(self.pool.max_slots) if s not in in_multi]
-        perm = [s for g in multi for s in g] + rest
-        return tuple(len(g) for g in multi), np.asarray(perm, np.int32)
-
     def _scan_inputs(self, run: List[LiveRequest], H: int):
         """Fixed-width (``max_slots``) batch arrays for one fused scan over
         ``run``: decoding mask, per-slot lengths and first tokens, and a
@@ -2199,8 +2161,7 @@ class PagedEngine(_QueueEngineBase):
         return m
 
     def _count_decode(self, run: List[LiveRequest], lengths: np.ndarray, H: int,
-                      T: int, nb: int, groups: Tuple[int, ...] = (),
-                      perm: Optional[np.ndarray] = None) -> None:
+                      T: int, nb: int) -> None:
         """Add one decode call to the waste counters: ``run`` decodes H
         steps of ``T`` queries from ``lengths`` through a block table
         ``nb`` wide.  Host copies only; nothing waits on the device."""
@@ -2211,16 +2172,9 @@ class PagedEngine(_QueueEngineBase):
                 lengths, H * T, self._attn_windows, self.pool.block_size)
         if self._mode != "block_sparse":
             return
-        L = self.model.cfg.n_layers
-        ids = None
-        for e in self.lc.in_state(ReqState.RUNNING, ReqState.SPECULATING):
-            if e.glass_key is not None:  # every other arena row is cleared
-                row = self._key_lists(e.glass_key)[0].reshape(L, -1)
-                if ids is None:
-                    ids = np.zeros((B,) + row.shape, np.int32)
-                ids[e.slot] = row
-        self.ffn_tiles_read += H * ffn_tile_fetches(ids, groups, perm, T)
-        union = np.logical_or.reduce([e.ffn_tiles for e in run])
+        tiles = [e.ffn_tiles for e in run]
+        self.ffn_tiles_read += H * ffn_tile_fetches(tiles)
+        union = np.logical_or.reduce(tiles)
         self.ffn_tiles_union += H * T * int(np.count_nonzero(union))
 
     # -- speculative decode (draft tier -> multi-token verify -> rollback) ---
@@ -2314,11 +2268,9 @@ class PagedEngine(_QueueEngineBase):
                 self.params, self.pool.cache, jnp.asarray(lengths), jnp.asarray(toks),
                 jnp.asarray(btab), jnp.asarray(decoding), self.glass_slots.draft_arena,
                 jnp.zeros((k, B), jnp.int32), jnp.zeros((k, B), bool),
-                jnp.zeros((B,), jnp.int32),
                 jnp.asarray(pos0), jnp.asarray(seeds), jnp.asarray(temp),
                 jnp.asarray(topk), jnp.asarray(topp), jnp.asarray(minp),
-                jnp.asarray(gmask), jnp.asarray(stop_ids),
-                (), sampled,
+                jnp.asarray(gmask), jnp.asarray(stop_ids), sampled,
             )
         with TraceAnnotation("engine.decode.dispatch"):
             seq, _, _, arena = self._decode(*args)
@@ -2367,11 +2319,8 @@ class PagedEngine(_QueueEngineBase):
                 for j in range(k):
                     ftoks[j, e.slot] = e.outputs[ck.out_len + j]
                     fmask[j, e.slot] = True
-            groups, perm = self._ffn_grouping(run)
             H, T = (1, k + 1) if self._verify_parallel else (k + 1, 1)
-            self._count_decode(run, lengths[decoding], H, T, btab.shape[1], groups, perm)
-            if perm is None:
-                perm = np.zeros((B,), np.int32)
+            self._count_decode(run, lengths[decoding], H, T, btab.shape[1])
             if self._verify_parallel:
                 # ONE T = k+1 forward instead of the k+1-step scan: the feed
                 # is fully known up front (pending + drafts, all forced), and
@@ -2385,21 +2334,19 @@ class PagedEngine(_QueueEngineBase):
                         feed[e.slot, j + 1] = e.outputs[ck.out_len + j]
                 args = (
                     self.params, self.pool.cache, jnp.asarray(lengths),
-                    jnp.asarray(feed), jnp.asarray(btab), self.glass_slots.arena,
-                    jnp.asarray(perm), jnp.asarray(pos0), jnp.asarray(seeds),
+                    jnp.asarray(feed), jnp.asarray(btab), jnp.asarray(decoding),
+                    self.glass_slots.arena, jnp.asarray(pos0), jnp.asarray(seeds),
                     jnp.asarray(temp), jnp.asarray(topk), jnp.asarray(topp),
-                    jnp.asarray(minp), jnp.asarray(gmask),
-                    groups, sampled,
+                    jnp.asarray(minp), jnp.asarray(gmask), sampled,
                 )
             else:
                 args = (
                     self.params, self.pool.cache, jnp.asarray(lengths), jnp.asarray(toks),
                     jnp.asarray(btab), jnp.asarray(decoding), self.glass_slots.arena,
-                    jnp.asarray(ftoks), jnp.asarray(fmask), jnp.asarray(perm),
+                    jnp.asarray(ftoks), jnp.asarray(fmask),
                     jnp.asarray(pos0), jnp.asarray(seeds), jnp.asarray(temp),
                     jnp.asarray(topk), jnp.asarray(topp), jnp.asarray(minp),
-                    jnp.asarray(gmask), jnp.asarray(stop_ids),
-                    groups, sampled,
+                    jnp.asarray(gmask), jnp.asarray(stop_ids), sampled,
                 )
         with TraceAnnotation("engine.decode.dispatch"):
             if self._verify_parallel:
@@ -2518,12 +2465,11 @@ class PagedEngine(_QueueEngineBase):
                 self.params, self.pool.cache, jnp.asarray(lengths), jnp.asarray(toks),
                 jnp.asarray(btab), jnp.asarray(decoding), self.glass_slots.arena,
                 jnp.asarray(ftoks), jnp.asarray(fmask),
-                jnp.zeros((B,), jnp.int32),
                 jnp.zeros((B,), jnp.int32), jnp.zeros((B,), jnp.int32),
                 jnp.ones((B,), jnp.float32), jnp.zeros((B,), jnp.int32),
                 jnp.ones((B,), jnp.float32), jnp.zeros((B,), jnp.float32),
                 jnp.ones((B,), bool), jnp.full((B, MAX_STOP_IDS), -1, jnp.int32),
-                (), False,
+                False,
             )
         with TraceAnnotation("engine.decode.dispatch"):
             _, _, _, arena = self._decode(*args)
@@ -2605,9 +2551,9 @@ class PagedEngine(_QueueEngineBase):
 
     def _decode_args(self, run: List[LiveRequest], H: int) -> tuple:
         """The decode program's arguments for one fused H-step scan over
-        ``run`` (growth already ensured): per-slot sampling policy, forced
-        replay re-feeds and the shared-list FFN grouping, copied to the
-        device.  Counts the call into the decode telemetry."""
+        ``run`` (growth already ensured): per-slot sampling policy and forced
+        replay re-feeds, copied to the device.  Counts the call into the
+        decode telemetry."""
         B = self.pool.max_slots
         decoding, lengths, toks, btab = self._scan_inputs(run, H)
         pos0, seeds, temp, topk, topp, minp, gmask, stop_ids, sampled = (
@@ -2623,22 +2569,15 @@ class PagedEngine(_QueueEngineBase):
                 for j in range(f):
                     ftoks[j, s] = e.outputs[start + j]
                     fmask[j, s] = True
-        groups, perm = self._ffn_grouping(run)
-        # grouped rows are live by construction (_ffn_grouping keys only
-        # RUNNING slots)
-        self.grouped_rows += H * sum(groups)
-        self._count_decode(run, lengths[decoding], H, 1, btab.shape[1], groups, perm)
-        if perm is None:
-            perm = np.zeros((B,), np.int32)  # unused when groups == ()
+        self._count_decode(run, lengths[decoding], H, 1, btab.shape[1])
         extra = self.glass_slots.arena if self.glass_slots is not None else None
         return (
             self.params, self.pool.cache, jnp.asarray(lengths), jnp.asarray(toks),
             jnp.asarray(btab), jnp.asarray(decoding), extra,
-            jnp.asarray(ftoks), jnp.asarray(fmask), jnp.asarray(perm),
+            jnp.asarray(ftoks), jnp.asarray(fmask),
             jnp.asarray(pos0), jnp.asarray(seeds), jnp.asarray(temp),
             jnp.asarray(topk), jnp.asarray(topp), jnp.asarray(minp),
-            jnp.asarray(gmask), jnp.asarray(stop_ids),
-            groups, sampled,
+            jnp.asarray(gmask), jnp.asarray(stop_ids), sampled,
         )
 
     def _plain_decode(self, run: List[LiveRequest], H: int, args: tuple,

@@ -213,7 +213,7 @@ class LiveRequest:
     replay_left: int = 0  # forced re-feeds outstanding after a recompute resume
     pstats: Any = None  # running-sum GLASS stats while PREFILLING
     glass_rows: Any = None  # saved per-slot GLASS rows while PREEMPTED_SWAPPED
-    glass_key: Optional[bytes] = None  # host active-block-list key (block_sparse)
+    glass_key: Optional[bytes] = None  # host block ids + tile scales (block_sparse)
     ffn_tiles: Any = None  # (L, n_tiles) bool tiles the key keeps (block_sparse)
     swap: Any = None  # BlockPool SwappedRequest while PREEMPTED_SWAPPED / MIGRATING
     swap_seq: int = -1  # swap-out order (cap overflow degrades the oldest store)

@@ -8,8 +8,6 @@ dynamic axes that would otherwise explode the jit cache:
   * gather width   — ``pow2_bucket`` over the block-table width ``nb``
   * scan horizon   — power-of-two ``H`` via the fused-decode horizon
   * glass mode     — a static of the program closure (one program per mode)
-  * group shape    — canonicalized shared-list group sizes (partitions, not
-                     compositions, of ``max_slots``)
 
 jax.jit keys its own cache on exactly those (shapes + statics), so the
 variant count per program is the product of the buckets actually served —

@@ -9,6 +9,7 @@ import numpy as np
 import pytest
 
 from repro.core import GlassConfig, GlassParams
+from repro.kernels.ops import ffn_union
 from repro.models import ModelConfig, build_model
 from repro.serve.engine import PagedEngine, attn_live_blocks, ffn_tile_fetches
 from repro.serve.lifecycle import ReqState
@@ -22,23 +23,31 @@ GC = GlassConfig(density=0.5, selection="block", block_size=32)
 BS = 8
 
 
-def lists(*rows):
-    """(B, L=1, nb) block lists, one per slot."""
-    return np.asarray(rows, np.int32)[:, None, :]
+def maps(*rows, layers=1):
+    """Per row, the (L, 4) bool map of the tiles it keeps: a row is a list
+    of kept tile ids per layer (one flat list when ``layers`` is 1)."""
+    out = []
+    for r in rows:
+        per_layer = [r] if layers == 1 else r
+        m = np.zeros((layers, 4), bool)
+        for layer, ids in enumerate(per_layer):
+            m[layer, ids] = True
+        out.append(m)
+    return out
 
 
-@pytest.mark.parametrize("ids, groups, perm, T, want", [
-    (lists([0, 2], [0, 2]), (2,), [0, 1], 1, 2),  # one group: its list once
-    (lists([0, 2], [1, 2]), (), None, 1, 4),  # rowwise: both lists
-    (lists([0, 2], [2, 3]), (), None, 1, 3),  # a step on the previous tile fetches nothing
-    (lists([0, 2], [1, 3], [0, 0], [0, 0]), (), None, 1, 5),  # cleared rows: tile 0 once
-    (lists([1, 3], [0, 2], [1, 3]), (2,), [0, 2, 1], 1, 4),  # a group, then the rest rowwise
-    (lists([0, 2]), (), None, 2, 4),  # T queries walk the row's list T times
-    (np.asarray([[[0, 2], [1, 3]], [[0, 2], [3, 1]]], np.int32), (), None, 1, 7),  # 2 layers
+@pytest.mark.parametrize("tiles, want", [
+    (maps([0, 2], [0, 2]), 2),  # identical lists: one list's tiles
+    (maps([0, 2], [1, 2]), 3),  # overlapping lists: their union
+    (maps([0, 2], [1, 3]), 4),  # disjoint lists: every tile once
+    (maps([0, 2], [1, 3], [], []), 4),  # cleared rows keep nothing, add nothing
+    (maps([1, 3], [0, 2], [1, 3]), 4),  # a repeated list adds nothing
+    (maps([0, 1, 3]), 3),  # one row: its own list, as a one-row grid reads it
+    (maps([[0, 2], [1, 3]], [[0, 2], [3, 1]], layers=2), 4),  # 2 layers: 2 + 2
+    (maps([[0, 2], []], layers=2), 3),  # a layer that keeps nothing fetches its pad tile
 ])
-def test_ffn_tile_fetches_by_hand(ids, groups, perm, T, want):
-    perm = None if perm is None else np.asarray(perm, np.int32)
-    assert ffn_tile_fetches(ids, groups, perm, T) == want
+def test_ffn_tile_fetches_by_hand(tiles, want):
+    assert ffn_tile_fetches(tiles) == want
 
 
 def test_attn_live_blocks_by_hand():
@@ -52,25 +61,23 @@ def test_attn_live_blocks_by_hand():
 
 def brute(args, windows, bs):
     """What one decode call's kernels are handed, counted grid step by grid
-    step: (tile fetches, union of kept tiles, blocks walked, live blocks)."""
+    step: (tile fetches, union of kept tiles, blocks walked, live blocks).
+    The FFN grid walks the union ``ffn_union`` builds from the call's lists
+    and decoding mask; a step fetches when its tile id differs from the
+    previous step's."""
     lengths, btab, dmask = (np.asarray(a) for a in (args[2], args[4], args[5]))
     idx, scale = np.asarray(args[6]["idx"]), np.asarray(args[6]["scale"])  # (L, B, nb)
-    H, perm, groups = args[7].shape[0], np.asarray(args[9]), args[18]
+    H = args[7].shape[0]
     L, B, _ = idx.shape
     nb = btab.shape[1]
+    grid = np.asarray(ffn_union(args[6]["idx"], args[6]["scale"], args[5],
+                                n_tiles=CFG.d_ff // GC.block_size)[0])
     read = union = live = 0
     for layer in range(L):
-        calls, off = [], 0
-        order = perm if groups else np.arange(B)
-        for g in groups:
-            calls.append(list(idx[layer, order[off]]))
-            off += g
-        calls.append([t for b in order[off:] for t in idx[layer, b]])
-        for tiles in calls:
-            prev = None
-            for t in tiles:
-                read += t != prev
-                prev = t
+        prev = None
+        for t in grid[layer]:
+            read += t != prev
+            prev = t
         union += len({t for b in np.flatnonzero(dmask)
                       for t, s in zip(idx[layer, b], scale[layer, b]) if s})
         for b in np.flatnonzero(dmask):
@@ -98,8 +105,7 @@ def serve(prompts, max_slots, glass=None, **kw):
 
     def spy(*args):
         now = eng.counters()
-        calls.append((tuple(now[k] - last[k] for k in keys), brute(args, windows, BS),
-                      args[18]))
+        calls.append((tuple(now[k] - last[k] for k in keys), brute(args, windows, BS)))
         last.update({k: now[k] for k in keys})
         return decode(*args)
 
@@ -125,22 +131,20 @@ def test_counters_match_what_each_decode_call_is_handed(case):
     glass = [GlassParams(density=0.25)] if case == "lower_density" else None
     eng, calls = serve(prompts, max_slots={"distinct": 4}.get(case, 2), glass=glass)
     assert calls
-    for got, want, _ in calls:
+    for got, want in calls:
         assert got == want
     c = eng.counters()
     assert [c[k] for k in ("t", "slot_steps", "kv_row_ticks")] == \
         [eng.t, eng.slot_steps, eng.kv_row_ticks]
     assert c["attn_blocks_walked"] > c["attn_blocks_live"] > 0
-    grouped = [(got, want) for got, want, groups in calls if groups]
-    if case == "same_prompt":
-        # identical lists and scales batch through one shared grid, which
-        # streams each kept tile once: read == union in every such call
-        assert grouped and all(got[0] == got[1] for got, _ in grouped)
+    # the union grid streams each kept tile once a step: read == union in
+    # every call, whether the lists coincide, differ, or drop tiles at 0.0
+    assert all(got[0] == got[1] for got, _ in calls)
     if case == "lower_density":
         # the row keeps its capacity list of 2 tiles a layer, and scales the
-        # one that density 0.25 drops by 0.0: fetched, but not in the union
+        # one that density 0.25 drops by 0.0: neither fetched nor in the union
         assert c["ffn_tiles_union"] == CFG.n_layers * eng.slot_steps
-        assert c["ffn_tiles_read"] >= 2 * c["ffn_tiles_union"]
+        assert c["ffn_tiles_read"] == c["ffn_tiles_union"]
 
 
 def test_migrated_request_keeps_its_tiles():

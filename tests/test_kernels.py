@@ -36,6 +36,95 @@ def test_glass_ffn_sweep(B, d, m, bs, act, gated, dtype):
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=tol, rtol=tol)
 
 
+
+def _row_lists(rng, B, n_tiles, nb, p_zero):
+    """B sorted lists of nb distinct tile ids and their {0.0, 1.0} scales."""
+    ids = np.stack([np.sort(rng.choice(n_tiles, nb, replace=False)) for _ in range(B)])
+    scale = (rng.rand(B, nb) >= p_zero).astype(np.float32)
+    return ids.astype(np.int32), scale
+
+
+@pytest.mark.parametrize("B,T,cleared,dtype", [
+    (5, 1, 0, jnp.float32),
+    (5, 1, 0, jnp.bfloat16),
+    (6, 1, 2, jnp.float32),  # two cleared rows: zero lists, zero scales
+    (1, 1, 0, jnp.float32),
+    (3, 3, 0, jnp.float32),  # T queries a row: (B, T) flattened to B*T rows
+    (4, 2, 1, jnp.bfloat16),
+])
+def test_glass_ffn_union_matches_rowwise(B, T, cleared, dtype):
+    """One shared-list grid over the union of the rows' lists, with a
+    per-row scale table, computes what the rowwise grid computes on each
+    row's own list (scales of 0.0 included); cleared rows read 0.0."""
+    from repro.kernels.glass_ffn import glass_ffn_block_sparse_rowwise
+    from repro.kernels.ops import ffn_union
+
+    d, m, bs, nb = 64, 512, 32, 6
+    rng = np.random.RandomState(B * 10 + T + cleared)
+    ks = jax.random.split(jax.random.fold_in(KEY, B * T), 4)
+    w = lambda k, shape: (jax.random.normal(k, shape, jnp.float32) * 0.1).astype(dtype)
+    wu, wg, wd = w(ks[0], (d, m)), w(ks[1], (d, m)), w(ks[2], (m, d))
+    x = jax.random.normal(ks[3], (B * T, d), dtype)
+    ids, scale = _row_lists(rng, B, m // bs, nb, 0.3)
+    ids[B - cleared:], scale[B - cleared:] = 0, 0.0
+    u_ids, count, table = ffn_union(jnp.asarray(ids)[None], jnp.asarray(scale)[None],
+                                    n_tiles=m // bs)
+    got = glass_ffn_block_sparse(
+        x, wu, wd, u_ids[0], wg, block_scale=jnp.repeat(table[0], T, axis=1),
+        n_active=count[0], block_size=bs, interpret=True)
+    want = glass_ffn_block_sparse_rowwise(
+        x, wu, wd, jnp.asarray(np.repeat(ids, T, axis=0)), wg,
+        block_scale=jnp.asarray(np.repeat(scale, T, axis=0)), block_size=bs,
+        interpret=True)
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=tol, rtol=tol)
+    live = (B - cleared) * T
+    assert not np.asarray(got)[live:].any()
+    assert int(count[0]) == len({t for r in range(B) for t, s in zip(ids[r], scale[r]) if s})
+
+
+@pytest.mark.parametrize("case", ["random", "unkept_tile", "decoding_mask", "empty_layer"])
+def test_ffn_union_matches_brute_force(case):
+    """ffn_union's ids, count and scale table against a NumPy brute force:
+    a tile listed only at scale 0.0, or only by rows that do not decode, is
+    not in the union; a layer that keeps nothing has count 0."""
+    from repro.kernels.ops import ffn_union
+
+    rng = np.random.RandomState(len(case))
+    L, B, n_tiles, nb = 3, 4, 12, 4
+    ids = np.stack([_row_lists(rng, B, n_tiles, nb, 0.25)[0] for _ in range(L)])
+    scale = np.stack([_row_lists(rng, B, n_tiles, nb, 0.25)[1] for _ in range(L)])
+    rows = np.ones(B, bool)
+    if case == "unkept_tile":  # tile 11 listed by row 0 alone, at scale 0.0
+        ids[0] = [[0, 3, 5, 11], [0, 2, 3, 5], [1, 3, 5, 7], [0, 1, 2, 3]]
+        scale[0] = 1.0
+        scale[0, 0, 3] = 0.0
+    if case == "decoding_mask":
+        rows[[1, 3]] = False
+    if case == "empty_layer":
+        scale[1] = 0.0
+    u_ids, count, table = (np.asarray(a) for a in ffn_union(
+        jnp.asarray(ids), jnp.asarray(scale), jnp.asarray(rows), n_tiles=n_tiles))
+    assert u_ids.shape == (L, n_tiles) and table.shape == (L, n_tiles, B)
+    for layer in range(L):
+        keep = sorted({int(t) for b in range(B) if rows[b]
+                       for t, s in zip(ids[layer, b], scale[layer, b]) if s > 0})
+        n = len(keep)
+        assert count[layer] == n
+        pad = keep[-1] if keep else 0
+        assert u_ids[layer].tolist() == keep + [pad] * (n_tiles - n)
+        want = np.zeros((n_tiles, B), np.float32)
+        for p, t in enumerate(keep):
+            for b in np.flatnonzero(rows):
+                hit = ids[layer, b] == t
+                want[p, b] = scale[layer, b][hit].sum()
+        np.testing.assert_array_equal(table[layer], want)
+    if case == "unkept_tile":
+        assert 11 not in u_ids[0, : count[0]]
+    if case == "empty_layer":
+        assert count[1] == 0 and not table[1].any()
+
+
 @given(
     st.sampled_from([64, 128, 256]),
     st.sampled_from([32, 64]),

@@ -488,31 +488,33 @@ def test_incremental_admits_more_than_full_slow():
                                           err_msg=f"{mode} uid={r.uid}")
 
 
-# -- shared-list kernel grouping ----------------------------------------------
+# -- block-sparse decode over the union of the rows' lists --------------------
 
 
 def test_grouped_block_sparse_step_builder_matches_ungrouped():
-    """launch.steps.make_decode_step_block_sparse(groups=...) — the dry-run
-    builder for the shared-list batched decode — must agree exactly with
-    the ungrouped (rowwise) builder on the same per-row block lists."""
+    """launch.steps.make_decode_step_block_sparse on per-row block lists —
+    one grid over their union, each row under its own scales — must pick
+    the tokens each row picks when it decodes alone on its own list (the
+    rowwise computation: a one-row grid over the row's tiles)."""
     from repro.launch.steps import make_decode_step_block_sparse
 
     model = build_model(DENSE)
     params = model.init(jax.random.key(0))
-    B, L, nb = 3, DENSE.n_layers, 2
-    cache = model.init_cache(B, 16)
+    B = 3
     tok = jnp.asarray([[5], [5], [9]], jnp.int32)
     clen = jnp.zeros((B,), jnp.int32)
-    # rows 0 and 1 share a block list (group of 2); row 2 differs
+    # rows 0 and 1 share a block list; row 2's differs, so the union of a
+    # layer's lists is larger than any one of them
     bidx = jnp.asarray(
-        [[[0, 2], [0, 2], [1, 2]], [[1, 0], [1, 0], [2, 0]]], jnp.int32
+        [[[0, 2], [0, 2], [1, 2]], [[0, 1], [0, 1], [0, 2]]], jnp.int32
     )  # (L, B, nb)
-    plain = make_decode_step_block_sparse(model, block_size=32)
-    grouped = make_decode_step_block_sparse(model, block_size=32, groups=(2,))
-    perm = jnp.asarray([0, 1, 2], jnp.int32)
-    want, _ = plain(params, cache, tok, clen, bidx)
-    got, _ = grouped(params, model.init_cache(B, 16), tok, clen, bidx, perm)
-    np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
+    step = jax.jit(make_decode_step_block_sparse(model, block_size=32))
+    got, _ = step(params, model.init_cache(B, 16), tok, clen, bidx)
+    for b in range(B):
+        want, _ = step(params, model.init_cache(1, 16), tok[b:b + 1], clen[b:b + 1],
+                       bidx[:, b])
+        np.testing.assert_array_equal(np.asarray(want)[0], np.asarray(got)[b],
+                                      err_msg=f"row {b}")
 
 
 def _sampled_roundtrip(kind):
@@ -627,10 +629,10 @@ def test_sampled_pressure_parity_engine_driven_slow():
 
 
 def test_block_sparse_groups_identical_lists_slow():
-    """Decode rows whose active-block lists coincide must batch through the
-    shared-list glass_ffn kernel (grouped_rows telemetry) and stay
-    token-identical to the masked reference; a row with a different list
-    falls back to rowwise in the same tick."""
+    """Decode rows whose active-block lists coincide share their tiles in
+    the union grid, which streams each kept tile once a step: fewer tiles
+    than the rows' lists hold together.  Every row stays token-identical
+    to the masked reference, the row with a different list too."""
     model = build_model(DENSE)
     params = model.init(jax.random.key(0))
     prior = _prior_for(DENSE)
@@ -644,16 +646,25 @@ def test_block_sparse_groups_identical_lists_slow():
         Request(uid=2, prompt=other_prompt, max_new=8, arrival=0),
     ]
     outs = {}
-    grouped = 0
+    together = [0]  # tiles the decoding rows' lists hold, summed over steps
     for mode in ("block_sparse", "masked"):
         eng = PagedEngine(model, params, max_slots=3, max_len=32, block_size=8,
                           chunk_tokens=3, glass=gc, global_prior=prior,
                           glass_mode=mode)
+        if mode == "block_sparse":
+            count = eng._count_decode
+
+            def spy(run, lengths, H, T, nb, count=count):
+                together[0] += H * T * sum(int(e.ffn_tiles.sum()) for e in run)
+                count(run, lengths, H, T, nb)
+
+            eng._count_decode = spy
         outs[mode] = eng.run([Request(r.uid, r.prompt, r.max_new, r.arrival)
                               for r in reqs])
         if mode == "block_sparse":
-            grouped = eng.grouped_rows
-    assert grouped > 0  # the shared-list kernel really served live rows
+            c = eng.counters()
+    assert c["ffn_tiles_read"] == c["ffn_tiles_union"] > 0
+    assert c["ffn_tiles_read"] < together[0]
     for r in reqs:
         np.testing.assert_array_equal(outs["block_sparse"][r.uid].tokens,
                                       outs["masked"][r.uid].tokens,

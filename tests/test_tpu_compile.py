@@ -11,6 +11,8 @@ process at a time may load the TPU library, and every xdist worker imports
 this file.  The persistent compilation cache is off around these compiles,
 because an entry written for a described chip cannot be read back without one.
 """
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -20,6 +22,7 @@ from repro.kernels.glass_ffn import (
     glass_ffn_block_sparse,
     glass_ffn_block_sparse_rowwise,
 )
+from repro.kernels.ops import ffn_union
 from repro.kernels.paged_attention import paged_attention
 
 # llama3-8b: d_model, d_ff, KV heads, queries per KV head, head dim
@@ -79,6 +82,38 @@ def test_glass_ffn_compiles_for_v5e(one_chip, rowwise, scaled):
     compiled = _compile(fn, a["x"], a["w_up"], a["w_down"], a["idx"], a["w_gate"],
                         a["scale"])
     assert compiled.out_info.shape == (SLOTS, D)
+
+
+@pytest.mark.parametrize("rows, d_ff", [(16, 14336), (80, 14336), (16, 11008)],
+                         ids=["mistral-decode", "mistral-verify", "yi"])
+def test_union_glass_ffn_compiles_for_v5e(one_chip, rows, d_ff):
+    """The shared-list kernel over a union of the rows' lists: a (tiles,
+    rows) scale table delivered a (1, rows, 1) block a step and the union's
+    length as a scalar, at Mistral-7B widths (112 tiles; 16 rows decoding,
+    80 = 16 slots x 5 verify positions) and Yi-9B's (86 tiles)."""
+    n = d_ff // FFN_BLOCK
+    s = lambda shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def fn(x, w_up, w_down, w_gate, ids, scale, count):
+        return glass_ffn_block_sparse(x, w_up, w_down, ids, w_gate, block_scale=scale,
+                                      n_active=count, block_size=FFN_BLOCK)
+
+    compiled = _compile(fn, s((rows, D)), s((D, d_ff)), s((d_ff, D)), s((D, d_ff)),
+                        s((n,), jnp.int32), s((n, rows), jnp.float32),
+                        s((), jnp.int32))
+    assert compiled.out_info.shape == (rows, D)
+    assert "glass_ffn_shared" in compiled.as_text()
+
+
+def test_ffn_union_compiles_for_v5e(one_chip):
+    """The union of 16 slots' lists (56 of 112 tiles each, 16 layers)."""
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    L, n = 16, 14336 // FFN_BLOCK
+    compiled = jax.jit(partial(ffn_union, n_tiles=n)).lower(
+        s((L, 16, n // 2), jnp.int32), s((L, 16, n // 2), jnp.float32),
+        s((16,), jnp.bool_)).compile()
+    ids, count, scale = compiled.out_info
+    assert (ids.shape, count.shape, scale.shape) == ((L, n), (L,), (L, n, 16))
 
 
 @pytest.mark.parametrize("T", [1, 3], ids=["decode", "verify"])
