@@ -1,19 +1,23 @@
 """Set-up of one run: the device, the compile meter, seeded weights, the
 GLASS prior and the engine under test.
 
-``CompileMeter``, ``memory`` and the prior over a seeded token corpus follow
-``chip_smoke.py``; the weights are the reference's layout, made on the device
-in one jitted call from the seed, so that the comparison that decides
-``correct`` takes nothing that the system under test made.
+``chip_smoke.py`` imports ``CompileMeter`` from here; ``memory`` and the
+prior over a seeded token corpus follow ``chip_smoke.py``.  The weights are
+the reference's layout, made on the device in one jitted call from the
+seed, so that the comparison that decides ``correct`` takes nothing that the
+system under test made.
 """
 from __future__ import annotations
 
+import dataclasses
 import importlib
 import json
 import sys
 from pathlib import Path
 
 import numpy as np
+
+from bench.work.attention import head_dim
 
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
@@ -103,29 +107,50 @@ def open_device(jax, chips: int):
     return devs[:chips]
 
 
+# ModelConfig field: the config.json key it is checked against, where the file has it
+CORE_KEYS = {
+    "n_layers": "num_hidden_layers", "d_model": "hidden_size", "d_ff": "intermediate_size",
+    "n_heads": "num_attention_heads", "n_kv_heads": "num_key_value_heads",
+    "vocab_size": "vocab_size", "rope_theta": "rope_theta", "norm_eps": "rms_norm_eps",
+    "tie_embeddings": "tie_word_embeddings", "dtype": "torch_dtype",
+}
+
+
+def _plain(v):
+    return [_plain(x) for x in v] if isinstance(v, (list, tuple)) else v
+
+
 def program_model(config: dict):
     """The system under test's model for a configuration file: the registry
-    entry cut as the file says, every width checked against the file."""
+    entry cut as the file says, checked against the file: each core
+    ``config.json`` key the file has, the head size and the window, every
+    entry of its ``"registry_fields"`` (``{ModelConfig field: value}``), and
+    the length of its ``"layers"``."""
     if str(ROOT / "src") not in sys.path:
         sys.path.insert(0, str(ROOT / "src"))
     from repro.configs import get_config
     from repro.models import build_model
 
     hf = config["hf_config"]
+    layers = config.get("layers")
+    if layers is not None and len(layers) != hf["num_hidden_layers"]:
+        raise ValueError(f"\"layers\" lists {len(layers)} layers; num_hidden_layers is "
+                         f"{hf['num_hidden_layers']}")
     cfg = get_config(config["registry"]).replace(**config.get("registry_overrides", {}))
-    want = {
-        "n_layers": hf["num_hidden_layers"], "d_model": hf["hidden_size"],
-        "d_ff": hf["intermediate_size"], "n_heads": hf["num_attention_heads"],
-        "n_kv_heads": hf["num_key_value_heads"], "vocab_size": hf["vocab_size"],
-        "head_dim": hf.get("head_dim") or hf["hidden_size"] // hf["num_attention_heads"],
-        "sliding_window": hf.get("sliding_window"), "rope_theta": float(hf["rope_theta"]),
-        "norm_eps": hf["rms_norm_eps"], "tie_embeddings": hf["tie_word_embeddings"],
-        "dtype": hf["torch_dtype"],
-    }
+    fields = config.get("registry_fields", {})
+    unknown = set(fields) - {f.name for f in dataclasses.fields(cfg)}
+    if unknown:
+        raise ValueError(f"registry_fields names no ModelConfig field: {sorted(unknown)}")
+    want = {f: hf[key] for f, key in CORE_KEYS.items() if key in hf}
+    if "rope_theta" in want:
+        want["rope_theta"] = float(want["rope_theta"])
+    want.update(head_dim=head_dim(hf), sliding_window=hf.get("sliding_window"))
+    want.update(fields)
     got = {k: getattr(cfg, k) for k in want}
-    if got != want:
-        diff = {k: (got[k], want[k]) for k in want if got[k] != want[k]}
-        raise ValueError(f"registry {config['registry']!r} differs from the file: {diff}")
+    diff = {k: (got[k], want[k]) for k in want if _plain(got[k]) != _plain(want[k])}
+    if diff:
+        raise ValueError(f"registry {config['registry']!r} differs from the file "
+                         f"(registry, file): {diff}")
     return build_model(cfg)
 
 

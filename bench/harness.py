@@ -11,6 +11,7 @@ import gc
 import importlib.util
 import json
 import os
+import resource
 import shutil
 import tempfile
 import sys
@@ -53,13 +54,13 @@ class Ctx:
     shape: dict
     peak: dict
     window_s: float
-    counters: Dict[str, float]  # engine counters' change over the window
+    counters: Dict[str, int]  # every ``PagedEngine.counters()`` entry's change over the window
     pool_rows: int
     compiles: int
     ticks: list  # per step() in the window: (time, [(uid, tokens before, decoded, prev), ...])
     served: Dict[int, Served]
     prefill_tokens: int  # prompt tokens of requests first answered in the window
-    trace: Optional[tr.Trace]
+    trace: Optional[tr.Trace]  # with the program's ``engine.*`` spans in ``trace.spans``
     lists: Callable[[], Dict[int, list]]  # uid -> per-layer kept block-id sets
 
 
@@ -137,6 +138,44 @@ class Loop:
             if self.now() > t_end:
                 raise RuntimeError(f"engine did not finish its work in {limit_s} s")
             self.step()
+
+
+class HostWatch:
+    """What the host did over the window, for the log: the garbage
+    collector's passes and their seconds, the process's CPU seconds, its
+    voluntary and involuntary context switches, and the load average."""
+
+    def __init__(self):
+        self.gc_s, self.gc_n, self._t = 0.0, 0, None
+        self.ru0 = resource.getrusage(resource.RUSAGE_SELF)
+        gc.callbacks.append(self._gc)
+
+    def _gc(self, phase, info):
+        if phase == "start":
+            self._t = time.perf_counter()
+        elif self._t is not None:
+            self.gc_s += time.perf_counter() - self._t
+            self.gc_n += 1
+
+    def close(self) -> dict:
+        gc.callbacks.remove(self._gc)
+        ru = resource.getrusage(resource.RUSAGE_SELF)
+        return {"gc_passes": self.gc_n, "gc_s": round(self.gc_s, 4),
+                "cpu_user_s": round(ru.ru_utime - self.ru0.ru_utime, 3),
+                "cpu_sys_s": round(ru.ru_stime - self.ru0.ru_stime, 3),
+                "ctx_switches": ru.ru_nvcsw - self.ru0.ru_nvcsw,
+                "ctx_switches_involuntary": ru.ru_nivcsw - self.ru0.ru_nivcsw,
+                "loadavg_1m": os.getloadavg()[0]}
+
+
+def longest_ticks(ticks: list, t_open: float = 0.0, k: int = 5) -> list:
+    """The ``k`` longest intervals between consecutive ticks of the window:
+    (seconds into the window at its end, its seconds, tokens it brought)."""
+    out, prev = [], t_open
+    for t, tick in ticks:
+        out.append((round(t, 3), round(t - prev, 4), sum(d for _, _, d, _ in tick)))
+        prev = t
+    return sorted(out, key=lambda x: -x[1])[:k]
 
 
 def itl_samples(ticks: list) -> list:
@@ -236,7 +275,8 @@ class Run:
                     span = jax.profiler.TraceAnnotation("bench.window")
                     span.__enter__()
                 compiles0 = self.meter.compiles
-                counters0 = (eng.t, eng.slot_steps, eng.kv_row_ticks)
+                counters0 = eng.counters()
+                watch = HostWatch()
                 t_open = time.perf_counter()
                 loop.record_ticks = True
             if t >= seconds:
@@ -256,10 +296,10 @@ class Run:
                     nxt = min(nxt, 0.0)
                 time.sleep(max(0.0, min(nxt, seconds) - t))
         w["window_s"] = time.perf_counter() - t_open
+        w["host"] = watch.close()
         loop.record_ticks = False
         w["compiles"] = self.meter.compiles - compiles0
-        w["counters"] = dict(zip(("t", "slot_steps", "kv_row_ticks"), np.subtract(
-            (eng.t, eng.slot_steps, eng.kv_row_ticks), counters0).tolist()))
+        w["counters"] = {k: int(v - counters0[k]) for k, v in eng.counters().items()}
         w["in_flight"] = len(loop.live)
         if trace:
             span.__exit__(None, None, None)
@@ -272,6 +312,16 @@ class Run:
         w["ticks"] = loop.ticks
         return w
 
+    def ctx(self, w: dict, served: Dict[int, Served], t_all: Optional[tr.Trace],
+            lists: Callable[[], Dict[int, list]]) -> Ctx:
+        """What the per-layer readers read of the window ``w``."""
+        return Ctx(self.shape, self.peak, w["window_s"], w["counters"],
+                   (self.cell["num_blocks"] - 1) * self.config["engine"]["block_size"],
+                   w["compiles"], w["ticks"], served,
+                   sum(len(s.prompt) for s in served.values()
+                       if s.first_at is not None and 0 <= s.first_at < w["window_s"]),
+                   t_all, lists)
+
     def e2e(self, w: dict) -> dict:
         served = self.loop.served
         itl = itl_samples(w["ticks"])
@@ -282,6 +332,8 @@ class Run:
             f"{len(itl)} gap samples, {w['decoded']} tokens decoded "
             f"({w['decoded'] / w['window_s']:.2f}/s), {w['compiles']} compiles, "
             f"{w['in_flight']} in flight at the close, counters {w['counters']}")
+        log(f"host over the window {json.dumps(w['host'])}; longest ticks (end s, s, tokens) "
+            f"{longest_ticks(w['ticks'])}")
         if ttft:
             log(f"ttft_ms p50 {percentile(ttft, 50)} p95 {percentile(ttft, 95)} "
                 f"max {max(ttft)} (n={len(ttft)})")
@@ -334,13 +386,8 @@ def run(workload: str, seed: int, seconds: float, trace: bool, t_start: float,
     else:
         t_all = tr.load(w["logdir"])
         shutil.rmtree(w["logdir"], ignore_errors=True)
-        ctx = Ctx(r.shape, r.peak, w["window_s"], w["counters"],
-                  (r.cell["num_blocks"] - 1) * config["engine"]["block_size"], w["compiles"],
-                  w["ticks"], served,
-                  sum(len(s.prompt) for s in served.values()
-                      if s.first_at is not None and 0 <= s.first_at < w["window_s"]),
-                  t_all,
-                  lambda: block_lists(jax, ref, config, r.params, r.toks, served, w["ticks"]))
+        ctx = r.ctx(w, served, t_all,
+                    lambda: block_lists(jax, ref, config, r.params, r.toks, served, w["ticks"]))
         got = {}
         for m in pl_names:
             v = metric_reader(m["name"])(ctx)
@@ -354,7 +401,8 @@ def run(workload: str, seed: int, seconds: float, trace: bool, t_start: float,
         if t_all.devices:
             d0 = t_all.devices[0]
             result["breakdown"] = {"device_ops": tr.top_ops(d0),
-                                   "idle_gaps": tr.idle_gaps(d0, t_all.host, *bounds)}
+                                   "idle_gaps": tr.idle_gaps(d0, t_all.host + t_all.spans,
+                                                             *bounds)}
     device["memory_peak_bytes"] = peak_bytes
     result["device"] = device
     if diag is not None:

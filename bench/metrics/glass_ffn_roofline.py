@@ -5,7 +5,8 @@ kernels' device time inside the decode programs.
 
 Both Pallas kernels' bodies are named ``_kernel``, so the trace names
 neither; a ``glass_ffn`` call is the TPU custom call that takes the FFN's
-gate and up matrices, ``bf16[d,f]``, as operands."""
+up (and gate) matrix, ``bf16[d,f]``, as an operand.  A configuration with
+no FFN layer reads nothing."""
 from bench import flops, steps
 from bench import trace as tr
 
@@ -13,11 +14,13 @@ DECODE = r"^jit_dec\b"
 
 
 def kernel(shape: dict) -> str:
-    return rf'(?s)^(?=.*custom_call_target="tpu_custom_call")(?=.*\[{shape["d"]},{shape["f"]}\])'
+    k = shape["kinds"]["ffn"]
+    return rf'(?s)^(?=.*custom_call_target="tpu_custom_call")(?=.*\[{k["d"]},{k["f"]}\])'
 
 
 def read(ctx):
-    if ctx.trace is None or not ctx.trace.devices or ctx.peak is None:
+    if (ctx.trace is None or not ctx.trace.devices or ctx.peak is None
+            or "ffn" not in ctx.shape["kinds"]):
         return None
     pat = kernel(ctx.shape)
     secs = sum(tr.kernel_seconds(d, pat, DECODE) for d in ctx.trace.devices) / len(ctx.trace.devices)

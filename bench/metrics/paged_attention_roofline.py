@@ -6,7 +6,8 @@ programs.
 
 Both Pallas kernels' bodies are named ``_kernel``, so the trace names
 neither; a ``paged_attention`` call is the TPU custom call that takes the
-paged K/V pool, ``[num_blocks, block_size, K, head_dim]``, as operands."""
+paged K/V pool, ``[num_blocks, block_size, K, head_dim]``, as operands.  A
+configuration with no attention layer reads nothing."""
 from bench import flops, steps
 from bench import trace as tr
 
@@ -14,11 +15,13 @@ DECODE = r"^jit_dec\b"
 
 
 def kernel(shape: dict) -> str:
-    return rf'(?s)^(?=.*custom_call_target="tpu_custom_call")(?=.*\[\d+,\d+,{shape["K"]},{shape["hd"]}\])'
+    k = shape["kinds"]["attention"]
+    return rf'(?s)^(?=.*custom_call_target="tpu_custom_call")(?=.*\[\d+,\d+,{k["K"]},{k["hd"]}\])'
 
 
 def read(ctx):
-    if ctx.trace is None or not ctx.trace.devices or ctx.peak is None:
+    if (ctx.trace is None or not ctx.trace.devices or ctx.peak is None
+            or "attention" not in ctx.shape["kinds"]):
         return None
     pat = kernel(ctx.shape)
     secs = sum(tr.kernel_seconds(d, pat, DECODE) for d in ctx.trace.devices) / len(ctx.trace.devices)

@@ -1,6 +1,7 @@
-"""The readings of the program's own spans and counters (``bench/probe.py``)
-and the named-kernel reader, on a small trace made here and on one traced
-run of a tiny cell on the CPU."""
+"""The readings of the program's own spans and counters (``bench/probe.py``
+and the readers on them) and the named-kernel reader, on a small trace made
+here and on one traced run of a tiny cell on the CPU."""
+import dataclasses
 import re
 import time
 
@@ -34,7 +35,7 @@ def test_step_host_time_leaves_out_the_wait():
 
 def test_gaps_named_by_the_innermost_program_span():
     t = small_trace()
-    got = probe.idle_gaps(t.devices[0], t.host + SPANS, 0.0, 10.0)
+    got = tr.idle_gaps(t.devices[0], t.host + SPANS, 0.0, 10.0)
     # 4-5.5: no span covers half of it, the harness's step most; 7-8: the
     # prefill chunk covers 0.85 of it, inside its engine.step and
     # bench.step; 9-10: engine.step but none of its phases
@@ -42,13 +43,16 @@ def test_gaps_named_by_the_innermost_program_span():
                    ["engine.step", pytest.approx(1.0)]]
 
 
-@pytest.mark.parametrize("host", [
-    small_trace().host,
-    [E("bench.window", 0.0, 10.0), E("bench.step", 0.0, 4.0), E("bench.step", 7.0, 3.0)],
+@pytest.mark.parametrize("host, first", [
+    (small_trace().host, "bench.step"),
+    ([E("bench.window", 0.0, 10.0), E("bench.step", 0.0, 4.0), E("bench.step", 7.0, 3.0)],
+     "outside any host span"),
 ])
-def test_gaps_without_program_spans_are_named_as_the_harness_names_them(host):
+def test_gaps_without_program_spans_are_named_as_the_harness_names_them(host, first):
     d = small_trace().devices[0]
-    assert probe.idle_gaps(d, host, 0.0, 10.0) == tr.idle_gaps(d, host, 0.0, 10.0)
+    assert tr.idle_gaps(d, host, 0.0, 10.0) == [
+        [first, pytest.approx(1.5)], ["bench.step", pytest.approx(1.0)],
+        ["bench.step", pytest.approx(1.0)]]
 
 
 def test_span_table():
@@ -89,6 +93,30 @@ def test_glass_ffn_decode_share_reads_nothing_without_named_kernels():
     assert read(ctx(None, {})) is None
 
 
+PROGRAM_COUNTERS = {"t": 5, "slot_steps": 9, "kv_row_ticks": 800, "ffn_tiles_read": 90,
+                    "ffn_tiles_union": 90, "attn_blocks_walked": 64, "attn_blocks_live": 16}
+
+
+@pytest.mark.parametrize("name, value", [("step_host_ms", 1450.0),
+                                         ("ffn_tiles_read_per_union", 1.0),
+                                         ("attn_blocks_walked_per_live", 4.0)])
+def test_program_readers_on_a_small_trace(name, value):
+    t = small_trace()
+    t.spans = SPANS
+    c = dataclasses.replace(ctx(t, {}), counters=PROGRAM_COUNTERS)
+    assert harness.metric_reader(name)(c) == pytest.approx(value)
+
+
+@pytest.mark.parametrize("name", ["step_host_ms", "ffn_tiles_read_per_union",
+                                  "attn_blocks_walked_per_live"])
+def test_program_readers_find_nothing_to_read(name):
+    # no trace, a trace with no program spans, counters that counted nothing
+    c = dataclasses.replace(ctx(small_trace(), {}),
+                            counters=dict.fromkeys(PROGRAM_COUNTERS, 0))
+    assert harness.metric_reader(name)(c) is None
+    assert harness.metric_reader(name)(dataclasses.replace(c, trace=None)) is None
+
+
 @pytest.fixture(scope="module")
 def traced():
     return probe.probe("mistral7b.short_long", SEED, 2.0, time.perf_counter(),
@@ -107,7 +135,8 @@ def children(parent, spans):
 
 
 def test_traced_run_spans_nest_in_the_harness_steps(traced):
-    spans, trace = traced["_spans"], traced["_trace"]
+    trace = traced["_trace"]
+    spans = trace.spans
     steps = [s for s in spans if s.name == "engine.step"]
     assert steps
     harness_steps = [h for h in trace.host if h.name == "bench.step"]
@@ -130,12 +159,15 @@ def test_traced_run_reads_the_program(traced):
     assert traced["step_host_ms"] is not None and traced["step_host_ms"] > 0
     assert traced["ffn_tiles_read_per_union"] >= 1.0
     assert traced["attn_blocks_walked_per_live"] >= 1.0
-    c, h = traced["counters"], traced["harness_counters"]
-    assert {k: c[k] for k in h} == h  # the harness's own counters over the same steps
+    c = traced["counters"]  # every counter of the program, over the window
+    assert set(c) == {"t", "slot_steps", "kv_row_ticks", "ffn_tiles_read", "ffn_tiles_union",
+                      "attn_blocks_walked", "attn_blocks_live"}
+    assert c["t"] > 0 and c["ffn_tiles_union"] > 0
     assert traced["spans"]["engine.step"][0] > 0
 
 
 def test_program_spans_leave_the_harness_readers_alone(traced):
-    # the trace loader keeps only the harness's spans, so every reader and
-    # the window's bounds read what they read without the program's
+    # the trace loader keeps the program's spans apart from the harness's,
+    # so every reader and the window's bounds read what they read before
     assert {h.name for h in traced["_trace"].host} == {"bench.window", "bench.step"}
+    assert all(s.name.startswith("engine.") for s in traced["_trace"].spans)

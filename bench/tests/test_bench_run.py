@@ -3,8 +3,12 @@ skipped, the rest of a run is driven.  A sound run is correct; a run whose
 engine alters a token where it is produced is not, nor one whose prefill
 leaves the KV pool unchanged; the control (the reference in fp8) reads a
 gap far above the program's; gaps between tokens stay positive across the
-window's opening; and without a TPU the command exits non-zero and prints
-no result."""
+window's opening; the window's host log orders its ticks longest first and
+counts the garbage collector's passes; a traced run hands the per-layer
+readers the program's counters and spans; and without a TPU the command
+exits non-zero and prints no result.  Also the checks made before set-up:
+the registry entry against the configuration file, and a layer kind with
+no work module."""
 import os
 import subprocess
 import sys
@@ -36,10 +40,10 @@ FILES = {
 SEED = 2**31 + 77
 
 
-def tiny_run(fault=None, control=False):
+def tiny_run(fault=None, control=False, trace=False):
     from bench import harness
 
-    return harness.run("mistral7b.short_long", SEED, 2.0, False, time.perf_counter(),
+    return harness.run("mistral7b.short_long", SEED, 2.0, trace, time.perf_counter(),
                        require_chip=False, files=FILES, fault=fault, control=control)
 
 
@@ -102,6 +106,51 @@ def test_prefill_that_leaves_the_kv_pool_unchanged_is_not_correct():
     assert got["checks"]["logit_gap_max"]["value"] > got["checks"]["logit_gap_max"]["limit"]
 
 
+def test_traced_run_reads_the_programs_counters_and_spans():
+    got = tiny_run(trace=True)
+    assert got["correct"] is True
+    m = {k: v["value"] for k, v in got["metrics"].items()}
+    # no TPU planes in a CPU trace: the device readers read nothing
+    assert set(m) == {"decode_rows_mean", "kv_blocks_used_share", "compiles_in_window",
+                      "step_host_ms", "ffn_tiles_read_per_union", "attn_blocks_walked_per_live"}
+    assert m["step_host_ms"] > 0
+    assert m["ffn_tiles_read_per_union"] >= 1.0 and m["attn_blocks_walked_per_live"] >= 1.0
+    assert got["metrics"]["ffn_tiles_read_per_union"]["unit"] == "ratio"
+
+
+NO_ROPE = {k: v for k, v in TINY_HF.items() if k != "rope_theta"}
+
+
+@pytest.mark.parametrize("change, error", [
+    (dict(hf_config=NO_ROPE), None),  # no position encoding key: nothing to compare
+    (dict(hf_config=dict(TINY_HF, rope_theta=5e5)), "rope_theta"),
+    (dict(hf_config=dict(TINY_HF, attention_head_dim=32)), None),
+    (dict(hf_config=dict(TINY_HF, attention_head_dim=64)), "head_dim"),
+    (dict(registry_fields={"ffn_act": "silu", "gated_ffn": True, "rope_type": "standard"}), None),
+    (dict(registry_fields={"ffn_act": "relu2", "gated_ffn": True}), "ffn_act"),
+    (dict(registry_fields={"gated": False}), "names no ModelConfig field"),
+    (dict(layers=[["attention", "ffn"]] * 2), None),
+    (dict(layers=[["attention", "ffn"]] * 3), "lists 3 layers"),
+])
+def test_program_model_checks_what_the_file_states(change, error):
+    from bench import build
+
+    config = dict(FILES["config"], **change)
+    if error is None:
+        assert build.program_model(config).cfg.n_layers == 2
+    else:
+        with pytest.raises(ValueError, match=error):
+            build.program_model(config)
+
+
+def test_a_kind_without_a_work_module_fails_before_setup():
+    from bench import harness
+
+    files = dict(FILES, config=dict(FILES["config"], layers=[["attention", "moe"]] * 2))
+    with pytest.raises(ValueError, match="no work module"):
+        harness.Run("mistral7b.short_long", SEED, require_chip=False, files=files)
+
+
 class _Out:
     def __init__(self, uid, toks, finished=False):
         self.uid, self.new_tokens, self.finished = uid, np.asarray(toks), finished
@@ -148,6 +197,23 @@ def test_gaps_are_positive_across_the_window_opening():
     gaps = harness.itl_samples(loop.ticks)
     assert len(gaps) == 12
     assert all(0 < g < 1.0 for g in gaps)
+
+
+def test_host_log_names_the_longest_ticks_and_the_collectors_passes():
+    """The window's host log: ticks ordered longest first, each with the
+    tokens it brought; a garbage-collector pass inside the watch is counted."""
+    import gc
+
+    from bench import harness
+
+    ticks = [(0.1, [(1, 3, 8, 0.0)]), (0.5, [(1, 11, 8, 0.1), (2, 4, 8, 0.1)]),
+             (0.6, [(1, 19, 8, 0.5)])]
+    assert harness.longest_ticks(ticks, k=2) == [(0.5, 0.4, 16), (0.1, 0.1, 8)]
+    watch = harness.HostWatch()
+    gc.collect()
+    host = watch.close()
+    assert host["gc_passes"] >= 1 and host["gc_s"] >= 0
+    assert watch._gc not in gc.callbacks
 
 
 def test_no_tpu_exits_nonzero_without_a_result():

@@ -1,13 +1,15 @@
 """The reduction from a trace to busy time, program and kernel time, idle
 gaps, and the per-layer readers over it, on a small trace made here."""
+import dataclasses
 import importlib.util
 import json
 from pathlib import Path
 
 import pytest
 
-from bench import flops, harness
+from bench import flops, harness, steps
 from bench import trace as tr
+from bench.tests.test_bench_flops import fake_kind, hybrid_config  # noqa: F401
 
 BENCH = Path(__file__).resolve().parents[1]
 E = tr.Event
@@ -80,9 +82,8 @@ def test_union_merges_overlaps():
     assert tr.union([(3, 4), (0, 2), (1, 3)]) == [(0, 4)]
 
 
-def ctx(t, lists):
-    config = json.loads((BENCH / "configs" / "mistral7b.json").read_text())
-    s = flops.shape(config)
+def ctx(t, lists, s=None):
+    s = s or flops.shape(json.loads((BENCH / "configs" / "mistral7b.json").read_text()))
     served = {1: harness.Served(prompt=[0] * 100, due=0.0, counted=True, tokens=[0] * 9,
                                 first_at=0.1),
               2: harness.Served(prompt=[0] * 50, due=0.0, counted=False, tokens=[0] * 5)}
@@ -129,3 +130,42 @@ def test_decode_steps_rebuilt_from_ticks():
     assert got[0] == (1, [(1, 100)])
     assert got[1] == (1, [(1, 101), (2, 50)])
     assert len(got) == 5 and got[-1] == (1, [(1, 104), (2, 53)])
+
+
+def test_roofline_and_mfu_readers_on_another_composition(fake_kind):  # noqa: F811
+    """A cut of 5 state-space mixers, 5 non-gated FFNs and 1 attention
+    layer: with each kernel's time equal to its least time, and a window as
+    long as the served work takes at the peak, each reader reads 100%."""
+    s = flops.shape(hybrid_config())
+    lists = {u: [set(range(u, u + 120)) for _ in range(5)] for u in (1, 2)}
+    c = ctx(None, lists, s)
+    decode = steps.decode_steps(c)
+    ffn_s = sum(flops.least_seconds(*flops.ffn_step_work(s, [lists[u] for u, _ in rows]), c.peak)
+                for _, rows in decode)
+    attn_s = sum(flops.least_seconds(*flops.attn_step_work(s, [x for _, x in rows]), c.peak)
+                 for _, rows in decode)
+    work = (sum(flops.decode_token_flops(s, x) for _, rows in decode for _, x in rows)
+            + flops.prefill_flops(s, 100))  # uid 1's prompt, answered in the window
+    ffn_op = ('%glass_ffn_shared.7 = bf16[16,1,8192]{2,1,0} custom-call(s32[240]{0} %a, '
+              'bf16[16,1,8192]{2,1,0} %x, bf16[8192,30720]{1,0} %u, bf16[30720,8192]{1,0} %d), '
+              'custom_call_target="tpu_custom_call"')
+    attn_op = ('%paged_attention.3 = bf16[16,1,64,128]{3,2,1,0} custom-call(s32[16,64]{1,0} %t, '
+               'bf16[16,1,64,128]{3,2,1,0} %q, bf16[1537,16,8,128]{3,2,1,0} %k, '
+               'bf16[1537,16,8,128]{3,2,1,0} %v), custom_call_target="tpu_custom_call"')
+    trace = tr.Trace([tr.Device([E(ffn_op, 0.0, ffn_s), E(attn_op, ffn_s, attn_s)],
+                                [E("jit_dec(1)", 0.0, ffn_s + attn_s)])],
+                     [E("bench.window", 0.0, 1.0)])
+    served = {**c.served, 1: dataclasses.replace(c.served[1], first_at=0.0)}  # in the window
+    c = dataclasses.replace(c, trace=trace, served=served,
+                            window_s=work / c.peak["bf16_flops_per_s"])
+    for name in ("glass_ffn_roofline", "paged_attention_roofline", "step_mfu"):
+        assert harness.metric_reader(name)(c) == pytest.approx(100.0), name
+
+
+@pytest.mark.parametrize("name, kind", [("glass_ffn_roofline", "ffn"),
+                                        ("paged_attention_roofline", "attention")])
+def test_a_roofline_reads_nothing_where_no_layer_holds_its_kind(name, kind):
+    lists = {u: [set(range(56)) for _ in range(16)] for u in (1, 2)}
+    c = ctx(small_trace(), lists)
+    kinds = {k: v for k, v in c.shape["kinds"].items() if k != kind}
+    assert harness.metric_reader(name)(dataclasses.replace(c, shape=dict(c.shape, kinds=kinds))) is None

@@ -2,14 +2,15 @@
 
 ``load`` reads the ``.xplane.pb`` that ``jax.profiler`` writes into plain
 events: per device, its operations (the ``XLA Ops`` line) and its programs
-(the ``XLA Modules`` line); and the host's spans named ``bench.*``, which
-the harness writes around its calls into the engine.  The reducers work on
-those plain events, so a test can feed them a small trace of its own.
+(the ``XLA Modules`` line); the host's spans named ``bench.*``, which the
+harness writes around its calls into the engine; and apart from those, the
+program's own host spans, named ``engine.*``.  The reducers work on those
+plain events, so a test can feed them a small trace of its own.
 """
 from __future__ import annotations
 
 import re
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from pathlib import Path
 from typing import Iterable, List, Optional
 
@@ -36,6 +37,7 @@ class Device:
 class Trace:
     devices: List[Device]
     host: List[Event]  # the harness's own spans
+    spans: List[Event] = field(default_factory=list)  # the program's, by start
 
 
 def _events(line, plane) -> List[Event]:
@@ -53,7 +55,7 @@ def load(logdir: str) -> Trace:
     if not files:
         raise FileNotFoundError(f"no .xplane.pb under {logdir}")
     pd = ProfileData.from_file(str(files[-1]))
-    devices, host = [], []
+    devices, host, spans = [], [], []
     for plane in pd.planes:
         lines = {line.name: line for line in plane.lines}
         if plane.name.startswith("/device:TPU:") and "XLA Ops" in lines:
@@ -61,8 +63,12 @@ def load(logdir: str) -> Trace:
                                   _events(lines["XLA Modules"], plane) if "XLA Modules" in lines else []))
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
-                host += [e for e in _events(line, plane) if e.name.startswith("bench.")]
-    return Trace(devices, host)
+                for e in _events(line, plane):
+                    if e.name.startswith("bench."):
+                        host.append(e)
+                    elif e.name.startswith("engine."):
+                        spans.append(e)
+    return Trace(devices, host, sorted(spans, key=lambda e: e.start))
 
 
 def union(intervals: Iterable[tuple]) -> List[tuple]:
@@ -114,22 +120,29 @@ def top_ops(dev: Device, n: int = 10) -> List[list]:
     return [[k, v] for k, v in sorted(agg.items(), key=lambda kv: -kv[1])[:n]]
 
 
+def name_gap(s: float, e: float, host: List[Event]) -> str:
+    """The innermost host span (the window's own aside) that covers more
+    than half of [s, e); where none does, the name whose spans cover most
+    of it."""
+    cover: dict = {}
+    inner = None
+    for h in host:
+        ov = min(h.end, e) - max(h.start, s)
+        if h.name == "bench.window" or ov <= 0:
+            continue
+        cover[h.name] = cover.get(h.name, 0.0) + ov
+        if 2 * ov > e - s and (inner is None or h.dur < inner.dur):
+            inner = h
+    if inner is not None:
+        return inner.name
+    return max(cover, key=cover.get) if cover else "outside any host span"
+
+
 def idle_gaps(dev: Device, host: List[Event], t0: float, t1: float, n: int = 10) -> List[list]:
     """The ``n`` longest gaps in [t0, t1) with no device operation, each
-    named by the host span (other than the window's own) that covers most
-    of it, [name, seconds]."""
+    named by ``name_gap`` over ``host`` (the harness's spans, and the
+    program's where given), [name, seconds]."""
     busy = union((max(e.start, t0), min(e.end, t1)) for e in dev.ops if e.end > t0 and e.start < t1)
     edges = [t0] + [x for iv in busy for x in iv] + [t1]
     gaps = [(edges[i], edges[i + 1]) for i in range(0, len(edges), 2) if edges[i + 1] > edges[i]]
-    out = []
-    for s, e in sorted(gaps, key=lambda g: g[0] - g[1])[:n]:
-        cover: dict = {}
-        for h in host:
-            if h.name == "bench.window":
-                continue
-            ov = min(h.end, e) - max(h.start, s)
-            if ov > 0:
-                cover[h.name] = cover.get(h.name, 0.0) + ov
-        name = max(cover, key=cover.get) if cover else "outside any host span"
-        out.append([name, e - s])
-    return out
+    return [[name_gap(s, e, host), e - s] for s, e in sorted(gaps, key=lambda g: g[0] - g[1])[:n]]
